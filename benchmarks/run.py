@@ -131,6 +131,8 @@ def main() -> None:
     ap.add_argument("only", nargs="*", metavar="BENCH",
                     help="run only the named benchmarks (default: all)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     seeds = (0,) if args.quick else (0, 1, 2)
     n_rounds = 20 if args.quick else 30
 

@@ -3,19 +3,16 @@ async boosting compiled into a single pjit/shard_map step over a device
 mesh — adaptive interval, buffers, compensation and the sync collective all
 inside jit (DESIGN.md §3-4).
 
-Run standalone (it forks no subprocess; it sets the placeholder-device flag
-itself, so run it in a fresh interpreter):
+One federated client per device: on a CPU host, ask XLA for virtual
+devices to get a multi-client mesh (the flag must be set before JAX
+starts):
 
-    PYTHONPATH=src python examples/fed_mesh_demo.py
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        PYTHONPATH=src python examples/fed_mesh_demo.py
 """
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import dataclasses
 
 import jax
-import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.paper_fedboost import FedBoostConfig
 from repro.sim.scenarios import DOMAINS
@@ -23,33 +20,17 @@ from repro.core import fed_mesh
 from repro.data import make_domain_data
 from repro.models.weak import stump_thresholds
 
-K = 8   # one federated client per device along the mesh's client axis
+K = jax.device_count()   # one federated client per device on the mesh
 dom = dataclasses.replace(DOMAINS["edge_vision"], n_clients=K)
-data = make_domain_data(dom, seed=0)
+x, y, xv, yv = fed_mesh.pack_clients(make_domain_data(dom, seed=0), K)
 
-# pack client shards into stacked arrays (K, n, F) / (K, n)
-n_local = min(c[0].shape[0] for c in data["clients"])
-x = jnp.stack([c[0][:n_local] for c in data["clients"]])
-y = jnp.stack([c[1][:n_local] for c in data["clients"]])
-xv_full, yv_full = data["val"]
-nvl = xv_full.shape[0] // K
-xv = xv_full[:K * nvl].reshape(K, nvl, -1)
-yv = yv_full[:K * nvl].reshape(K, nvl)
-
-mesh = jax.make_mesh((K,), ("clients",))
+mesh = fed_mesh.client_mesh(jax.devices())
 cfg = FedBoostConfig(n_clients=K)
 thresholds = stump_thresholds(x.reshape(-1, x.shape[-1]))
 step = fed_mesh.make_fed_boost_step(cfg, mesh, "clients", thresholds)
-state = fed_mesh.init_state(cfg, K, n_local, nvl, buffer_cap=8,
+state = fed_mesh.init_state(cfg, K, x.shape[1], xv.shape[1], buffer_cap=8,
                             ens_cap=2048, key=jax.random.key(0))
-
-shardings = jax.tree.map(
-    lambda s: NamedSharding(mesh, s),
-    fed_mesh.state_shardings(mesh, "clients"),
-    is_leaf=lambda v: isinstance(v, P))
-dsh = NamedSharding(mesh, P("clients"))
-state = jax.device_put(state, shardings)
-x, y, xv, yv = (jax.device_put(a, dsh) for a in (x, y, xv, yv))
+state, x, y, xv, yv = fed_mesh.place(mesh, "clients", state, x, y, xv, yv)
 
 jstep = jax.jit(step, donate_argnums=0)
 print(f"{K} clients on a {mesh.devices.shape} mesh; "

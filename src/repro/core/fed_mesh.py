@@ -24,12 +24,12 @@ and counted by the §Roofline collective parser.
 """
 from __future__ import annotations
 
-import functools
-from typing import Dict, NamedTuple, Tuple
+from typing import Dict, NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+import numpy as np
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro import obs
 from repro.configs.paper_fedboost import FedBoostConfig
@@ -38,12 +38,8 @@ from repro.core.compensation import adaboost_alpha, compensate
 
 Array = jnp.ndarray
 
-if hasattr(jax, "shard_map"):        # jax >= 0.6
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-else:                                # older jax: experimental home, check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
+# weighted errors this close to the best stump's count as ties
+SELECT_TOL = 1e-5
 
 
 class FedMeshState(NamedTuple):
@@ -80,20 +76,62 @@ def _predict_stumps(params: Array, x: Array) -> Array:
 
 def _fit_stump_local(x: Array, y: Array, D: Array, thresholds: Array
                      ) -> Tuple[Array, Array]:
-    """Returns (params (4,), eps scalar).  Pure jnp so it shard_maps."""
+    """Returns (params (4,), eps scalar).  Pure jnp so it shard_maps.
+
+    Candidates whose error is within ``SELECT_TOL`` of the best are ties,
+    broken by the lowest index, polarity +1 first.  Two stumps that
+    misclassify equal weight differ only by the rounding of their sums,
+    and that rounding follows the summation order, which differs between
+    backends — so without the tolerance the chip and the host pick
+    different stumps from the same data."""
     pred = jnp.where(x[:, :, None] > thresholds[None, :, :], 1.0, -1.0)
     miss = (pred != y[:, None, None]).astype(jnp.float32)
-    err_pos = jnp.einsum("n,nft->ft", D, miss)
-    err_neg = 1.0 - err_pos
-    i_pos = jnp.argmin(err_pos)
-    i_neg = jnp.argmin(err_neg)
-    take_pos = err_pos.reshape(-1)[i_pos] <= err_neg.reshape(-1)[i_neg]
-    idx = jnp.where(take_pos, i_pos, i_neg)
-    f, t = jnp.unravel_index(idx, err_pos.shape)
-    pol = jnp.where(take_pos, 1.0, -1.0)
-    eps = jnp.where(take_pos, err_pos.reshape(-1)[i_pos],
-                    err_neg.reshape(-1)[i_neg])
+    # HIGHEST: at default precision the TPU rounds D to bfloat16
+    err_pos = jnp.einsum("n,nft->ft", D, miss,
+                         precision=jax.lax.Precision.HIGHEST).reshape(-1)
+    errs = jnp.concatenate([err_pos, 1.0 - err_pos])
+    idx = jnp.argmax(errs <= jnp.min(errs) + SELECT_TOL)
+    f, t = jnp.unravel_index(idx % err_pos.shape[0], thresholds.shape)
+    pol = jnp.where(idx < err_pos.shape[0], 1.0, -1.0)
+    eps = errs[idx]
     return jnp.stack([f.astype(jnp.float32), thresholds[f, t], pol, eps]), eps
+
+
+def client_mesh(devices: Sequence, axis: str = "clients") -> Mesh:
+    """A 1-D mesh with one federated client per device.  The axis is
+    Auto-typed: the step gathers from replicated tables with client-sharded
+    indices, which an Explicit axis (``jax.make_mesh``'s default) refuses
+    without a per-gather ``out_sharding``."""
+    return Mesh(np.asarray(devices), (axis,), axis_types=(AxisType.Auto,))
+
+
+def pack_clients(data: Dict, n_clients: int) -> Tuple[Array, ...]:
+    """Stack a ``make_domain_data`` split into the step's inputs: client
+    shards truncated to the smallest, ``(K, n, F)`` / ``(K, n)``, and the
+    validation set cut into K equal slices ``(K, n_val, F)`` /
+    ``(K, n_val)``."""
+    K = n_clients
+    n_local = min(c[0].shape[0] for c in data["clients"][:K])
+    x = jnp.stack([c[0][:n_local] for c in data["clients"][:K]])
+    y = jnp.stack([c[1][:n_local] for c in data["clients"][:K]])
+    xv_full, yv_full = data["val"]
+    nvl = xv_full.shape[0] // K
+    xv = jnp.asarray(xv_full[:K * nvl]).reshape(K, nvl, -1)
+    yv = jnp.asarray(yv_full[:K * nvl]).reshape(K, nvl)
+    return x, y, xv, yv
+
+
+def place(mesh: Mesh, client_axis: str, state: "FedMeshState",
+          *data: Array) -> Tuple:
+    """Put ``state`` and the client-sharded inputs on ``mesh``: leaves with
+    a leading client axis are split over ``client_axis``, the ensemble and
+    controller leaves are replicated."""
+    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                             state_shardings(mesh, client_axis),
+                             is_leaf=lambda v: isinstance(v, P))
+    dsh = NamedSharding(mesh, P(client_axis))
+    return (jax.device_put(state, shardings),
+            *(jax.device_put(a, dsh) for a in data))
 
 
 def init_state(cfg: FedBoostConfig, n_clients: int, n_local: int,
@@ -225,9 +263,9 @@ def make_fed_boost_step(cfg: FedBoostConfig, mesh, client_axis: str,
                     P(client_axis), P(client_axis), P(client_axis),
                     P(client_axis), P(client_axis), P(client_axis))
         specs_out = (P(), P(), P(), P(client_axis), P(client_axis), P())
-        ens_p, ens_a, n_new, D, val_margin, g_err = _shard_map(
+        ens_p, ens_a, n_new, D, val_margin, g_err = jax.shard_map(
             gather_merge, mesh=mesh, in_specs=specs_in, out_specs=specs_out,
-            **_SHARD_MAP_KW)(
+            check_vma=False)(
                 state.buf_params, state.buf_stamp, state.buf_count,
                 state.D, x, y, state.val_margin, xv, yv)
 

@@ -145,17 +145,18 @@ class FleetCore:
         """Resolve the batched-fit backend.  No policy keeps the jnp
         oracle (a single vmapped XLA launch — the right default off-TPU);
         a policy resolves normally except that the *interpret* substrate is
-        swapped for ``xla`` at fleet batch sizes, where a vmapped
-        interpreter launch is pathological."""
+        swapped for ``xla`` at fleet batch sizes, where an interpreter
+        launch is pathological.  The backend that runs is what
+        ``policy.choices`` records."""
         policy = self.eng.kernel_policy
         if policy is None:
             return None
         from repro.kernels import dispatch as kdispatch
-        name = policy.resolve_name(
-            "stump_scan_batched",
-            kdispatch.bucket_of("stump_scan_batched", xb))
+        bucket = kdispatch.bucket_of("stump_scan_batched", xb)
+        name = policy.resolve("stump_scan_batched", bucket).name
         if name == "interpret" and xb[0].shape[0] >= 64:
-            return "xla"
+            name = "xla"
+            policy.choices[("stump_scan_batched", bucket)] = name
         return name
 
     def _fit_wave(self, slots: np.ndarray

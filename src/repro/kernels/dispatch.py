@@ -4,8 +4,8 @@ Every public kernel entry point in :mod:`repro.kernels.ops` routes through
 this module.  Three execution substrates implement the same numerical
 contract (asserted against each other in ``tests/test_backend_parity.py``):
 
-* ``interpret`` — the Pallas kernels under the Pallas interpreter.  Runs on
-  any JAX backend; the CPU-container default.
+* ``interpret`` — the Pallas kernels under the Pallas interpreter.  The
+  default off the TPU; refused on it.
 * ``mosaic``    — the same Pallas kernels compiled by Mosaic.  TPU only.
 * ``xla``       — the pure-jnp oracles from :mod:`repro.kernels.ref`,
   jit-compiled by XLA.  Always available; the fallback of last resort and
@@ -21,9 +21,11 @@ the next kernel launch), in priority order::
     > the policy's calibration table (per (kernel, shape-bucket) winner)
     > platform default ("mosaic" on TPU, "interpret" elsewhere)
 
-An unavailable candidate (e.g. ``mosaic`` off-TPU) falls through to the
+Off the TPU, an unavailable candidate (``mosaic``) falls through to the
 next priority with a one-shot RuntimeWarning, so a policy calibrated on one
-substrate degrades gracefully on another.
+substrate degrades gracefully on another.  On the TPU nothing falls
+through: a candidate that cannot run there (``interpret``, from any level)
+raises, so the interpreter never runs on the chip unnoticed.
 
 Shapes are *bucketed* by rounding each dimension up to the block boundary
 the padded Pallas call would use — under the kernel's **reference layout**
@@ -49,7 +51,6 @@ sweep pass.
 """
 from __future__ import annotations
 
-import functools
 import json
 import os
 import statistics
@@ -66,18 +67,16 @@ from repro import obs
 from repro.kernels import ref
 from repro.kernels.dist_update import dist_update_kernel
 from repro.kernels.ensemble_vote import (
-    ensemble_vote_batched_kernel, ensemble_vote_kernel,
-    stump_vote_batched_kernel, stump_vote_fp_batched_kernel)
+    ensemble_vote_batched_kernel, stump_vote_batched_kernel,
+    stump_vote_fp_batched_kernel)
 from repro.kernels.flash_attention import flash_attention_kernel
 from repro.kernels.stump_scan import stump_scan_kernel
 
 ENV_VAR = "REPRO_KERNEL_BACKEND"
 DEFAULT_CALIBRATION_PATH = "artifacts/backend_calibration.json"
 CALIBRATION_SCHEMA_VERSION = 2
-
-# (measured_on, running_on) pairs already warned about — the cross-
-# platform calibration warning fires once per process per pair
-_PLATFORM_WARNED: set = set()
+# sample rows (slots x block_n) one stump-sweep grid step covers
+STUMP_ROWS_PER_STEP = 512
 
 Bucket = Tuple[int, ...]
 Layout = Dict[str, int]                 # block-shape kwargs of one launch
@@ -226,84 +225,77 @@ def _flash_blocks(T: int, block_q: int, block_k: int) -> Tuple[int, int]:
 # Pallas substrate: pad to hardware-aligned blocks, launch, slice back
 # ---------------------------------------------------------------------------
 
-def _pallas_stump_scan(x, y, w, thresholds, *, block_n=256, interpret=True):
-    # pad N with zero-weight rows (no contribution) and F/T to the 8-sublane
-    # boundary (inf thresholds never win the argmin)
-    N, F = x.shape
-    T = thresholds.shape[1]
-    xp = pad_to(x, 0, block_n)
-    yp = pad_to(y, 0, block_n, value=1.0)
-    wp = pad_to(w, 0, block_n, value=0.0)
-    xp = pad_to(xp, 1, 8)
-    thr = pad_to(pad_to(thresholds, 0, 8, value=jnp.inf), 1, 8,
-                 value=jnp.inf)
-    err = stump_scan_kernel(xp, yp, wp, thr, block_n=block_n,
-                            interpret=interpret)
-    return err[:F, :T]
-
-
 def _pallas_stump_scan_batched(x, y, w, thresholds, *, block_n=256,
                                interpret=True):
-    # vmap lifts the batch dim onto the launch grid; per-slot padding is
-    # identical to _pallas_stump_scan.  block_n shrinks to the next power
-    # of two covering N so fleet batches of tiny shards don't pad 64x.
-    N = x.shape[1]
+    # pad N with zero-weight rows and B with zero-weight slots (neither
+    # contributes); block_n shrinks to the next power of two covering N so
+    # fleet batches of tiny shards don't pad 64x, and several slots share
+    # one grid step so a step holds about STUMP_ROWS_PER_STEP sample rows
+    B, N, F = x.shape
     bn = min(block_n, max(8, next_pow2(N)))
-    fn = functools.partial(_pallas_stump_scan, block_n=bn,
-                           interpret=interpret)
-    return jax.vmap(fn)(x, y, w, thresholds)
+    bb = min(next_pow2(B), max(1, STUMP_ROWS_PER_STEP // bn))
+    xp = pad_to(pad_to(x, 1, bn), 0, bb)
+    yp = pad_to(pad_to(y, 1, bn, value=1.0), 0, bb, value=1.0)
+    wp = pad_to(pad_to(w, 1, bn), 0, bb)
+    tp = pad_to(jnp.swapaxes(thresholds, 1, 2), 0, bb)
+    err = stump_scan_kernel(xp, yp[..., None], wp[..., None], tp,
+                            block_b=bb, block_n=bn, interpret=interpret)
+    return jnp.swapaxes(err, 1, 2)[:B]
 
 
-def _pallas_ensemble_vote(margins, alphas, *, block_t=128, block_n=512,
-                          interpret=True):
-    # pad T with zero-alpha rows and N with dummy columns (sliced off)
-    T, N = margins.shape
-    bt, bn = vote_blocks(T, N, block_t, block_n)
-    mp = pad_to(pad_to(margins, 0, bt), 1, bn)
-    ap = pad_to(alphas, 0, bt, value=0.0)
-    out = ensemble_vote_kernel(mp, ap, block_t=bt, block_n=bn,
-                               interpret=interpret)
-    return out[:N]
+def _pallas_stump_scan(x, y, w, thresholds, *, block_n=256, interpret=True):
+    # the B = 1 case of the batched sweep
+    return _pallas_stump_scan_batched(
+        x[None], y[None], w[None], thresholds[None], block_n=block_n,
+        interpret=interpret)[0]
 
 
 def _pallas_ensemble_vote_batched(margins, alphas, *, block_t=128,
                                   block_n=512, interpret=True):
+    # pad T with zero-alpha rows and N with dummy columns (sliced off)
     B, T, N = margins.shape
     bt, bn = vote_blocks(T, N, block_t, block_n)
     mp = pad_to(pad_to(margins, 1, bt), 2, bn)
     ap = pad_to(alphas, 1, bt, value=0.0)
-    out = ensemble_vote_batched_kernel(mp, ap, block_t=bt, block_n=bn,
-                                       interpret=interpret)
-    return out[:, :N]
+    out = ensemble_vote_batched_kernel(mp, ap[..., None], block_t=bt,
+                                       block_n=bn, interpret=interpret)
+    return out[:, 0, :N]
+
+
+def _pallas_ensemble_vote(margins, alphas, *, block_t=128, block_n=512,
+                          interpret=True):
+    # the one-tenant case of the batched vote
+    return _pallas_ensemble_vote_batched(
+        margins[None], alphas[None], block_t=block_t, block_n=block_n,
+        interpret=interpret)[0]
+
+
+def _stump_vote_operands(xsel, thr, pol, alphas, block_t, block_n):
+    # zero-alpha padding rows nullify whatever thr/pol padding holds, in
+    # the vote and in the alpha-gated fingerprint alike
+    B, T, N = xsel.shape
+    bt, bn = vote_blocks(T, N, block_t, block_n)
+    xp = pad_to(pad_to(xsel, 1, bt), 2, bn)
+    cols = [pad_to(v, 1, bt, value=fill)[..., None]
+            for v, fill in ((thr, 0.0), (pol, 1.0), (alphas, 0.0))]
+    return (xp, *cols), dict(block_t=bt, block_n=bn)
 
 
 def _pallas_stump_vote_batched(xsel, thr, pol, alphas, *, block_t=128,
                                block_n=512, interpret=True):
-    # zero-alpha padding rows nullify whatever thr/pol padding holds
-    B, T, N = xsel.shape
-    bt, bn = vote_blocks(T, N, block_t, block_n)
-    xp = pad_to(pad_to(xsel, 1, bt), 2, bn)
-    tp = pad_to(thr, 1, bt, value=0.0)
-    pp = pad_to(pol, 1, bt, value=1.0)
-    ap = pad_to(alphas, 1, bt, value=0.0)
-    out = stump_vote_batched_kernel(xp, tp, pp, ap, block_t=bt, block_n=bn,
-                                    interpret=interpret)
-    return out[:, :N]
+    args, blocks = _stump_vote_operands(xsel, thr, pol, alphas, block_t,
+                                        block_n)
+    out = stump_vote_batched_kernel(*args, interpret=interpret, **blocks)
+    return out[:, 0, :xsel.shape[2]]
 
 
 def _pallas_stump_vote_fp_batched(xsel, thr, pol, alphas, *, block_t=128,
                                   block_n=512, interpret=True):
-    # same padding contract as stump_vote_batched; the alpha-gated xor
-    # fold makes the fingerprint outputs padding-invariant too
-    B, T, N = xsel.shape
-    bt, bn = vote_blocks(T, N, block_t, block_n)
-    xp = pad_to(pad_to(xsel, 1, bt), 2, bn)
-    tp = pad_to(thr, 1, bt, value=0.0)
-    pp = pad_to(pol, 1, bt, value=1.0)
-    ap = pad_to(alphas, 1, bt, value=0.0)
-    out, f0, f1 = stump_vote_fp_batched_kernel(
-        xp, tp, pp, ap, block_t=bt, block_n=bn, interpret=interpret)
-    return out[:, :N], f0[:, :N], f1[:, :N]
+    args, blocks = _stump_vote_operands(xsel, thr, pol, alphas, block_t,
+                                        block_n)
+    N = xsel.shape[2]
+    return tuple(o[:, 0, :N] for o in stump_vote_fp_batched_kernel(
+        *args, interpret=interpret, **blocks))
 
 
 def _pallas_flash_attention(q, k, v, *, causal=True, block_q=128,
@@ -464,11 +456,11 @@ def bucket_of(kernel: str, args: Sequence, kwargs: Optional[dict] = None
 # ---------------------------------------------------------------------------
 
 class PallasInterpretBackend:
-    """Pallas kernels under the interpreter — correct everywhere."""
+    """Pallas kernels under the interpreter — off the TPU only."""
     name = "interpret"
 
     def available(self) -> bool:
-        return True
+        return not on_tpu()
 
     def run(self, kernel: str, *args, **kwargs):
         return _PALLAS_IMPLS[kernel](*args, interpret=True, **kwargs)
@@ -580,8 +572,9 @@ class KernelPolicy:
 
     def resolve_name(self, kernel: str, bucket: Bucket, *,
                      explicit: Optional[str] = None) -> str:
-        """Backend name for one (kernel, bucket) call, skipping candidates
-        whose substrate is unavailable on the current platform."""
+        """Backend name for one (kernel, bucket) call.  Off the TPU a
+        candidate whose substrate is unavailable is skipped with a warning;
+        on the TPU it raises."""
         bucket = tuple(bucket)
         entry = self.table.get((kernel, bucket))
         for cand in (explicit, self.backend, self._env_backend(),
@@ -591,6 +584,11 @@ class KernelPolicy:
             name = canonical(cand)
             if BACKENDS[name].available():
                 return name
+            if on_tpu():
+                raise RuntimeError(
+                    f"kernel backend '{name}' cannot run on the TPU "
+                    f"({kernel} {bucket_label(bucket)}); choose 'mosaic' or "
+                    f"'xla', or unset {ENV_VAR}")
             if name not in self._warned:
                 self._warned.add(name)
                 warnings.warn(
@@ -712,10 +710,9 @@ class KernelPolicy:
         """Load a persisted table.  Schema v1 (backend-only entries, no
         ``version`` field) loads transparently with empty layouts — the
         reference ``DEFAULT_LAYOUTS`` then apply at dispatch time.  A
-        table measured on a different platform warns once per process:
-        its tuned layouts still load (they are only hints) but say
-        nothing about this substrate — re-run benchmarks.backend_matrix
-        here to re-measure."""
+        non-empty table measured on a different platform raises: its
+        winners say nothing about this substrate — re-run
+        benchmarks.backend_matrix here to re-measure."""
         data = json.loads(Path(path).read_text())
         version = int(data.get("version", 1))
         if version > CALIBRATION_SCHEMA_VERSION:
@@ -724,16 +721,12 @@ class KernelPolicy:
                 f"build reads up to v{CALIBRATION_SCHEMA_VERSION}")
         measured_on = data.get("measured_on")
         platform = jax.default_backend()
-        if (measured_on and measured_on != platform
-                and data.get("table")
-                and (measured_on, platform) not in _PLATFORM_WARNED):
-            _PLATFORM_WARNED.add((measured_on, platform))
-            warnings.warn(
+        if measured_on and measured_on != platform and data.get("table"):
+            raise ValueError(
                 f"calibration table {path!r} was measured on "
                 f"'{measured_on}' but this process runs on '{platform}'; "
-                f"its tuned (backend, layout) winners may not transfer — "
                 f"re-run `python -m benchmarks.backend_matrix` on this "
-                f"platform to re-measure", RuntimeWarning, stacklevel=2)
+                f"platform to re-measure")
         pol = cls(backend=data.get("backend"),
                   table={(e["kernel"], tuple(e["bucket"])):
                          CalEntry(canonical(e["backend"]),

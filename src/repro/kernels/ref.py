@@ -2,7 +2,9 @@
 
 Each ``*_ref`` function defines the semantics the kernel must match
 (asserted allclose in tests over shape/dtype sweeps, with the kernel run in
-interpret mode on CPU).
+interpret mode on CPU).  Every contraction runs at ``HIGHEST`` precision:
+at the default precision the TPU rounds float32 operands to bfloat16,
+which would move the stump argmin and flip near-zero margins.
 """
 from __future__ import annotations
 
@@ -10,6 +12,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def stump_scan_ref(x: jnp.ndarray, y: jnp.ndarray, w: jnp.ndarray,
@@ -24,7 +28,8 @@ def stump_scan_ref(x: jnp.ndarray, y: jnp.ndarray, w: jnp.ndarray,
     """
     pred = jnp.where(x[:, :, None] > thresholds[None, :, :], 1.0, -1.0)
     miss = (pred != y[:, None, None]).astype(jnp.float32)
-    return jnp.einsum("n,nft->ft", w.astype(jnp.float32), miss)
+    return jnp.einsum("n,nft->ft", w.astype(jnp.float32), miss,
+                      precision=_EXACT)
 
 
 def stump_scan_batched_ref(x: jnp.ndarray, y: jnp.ndarray, w: jnp.ndarray,
@@ -45,7 +50,7 @@ def ensemble_vote_ref(margins: jnp.ndarray, alphas: jnp.ndarray) -> jnp.ndarray:
     (already staleness-compensated) -> (N,) f32 ensemble margin.
     """
     return jnp.einsum("t,tn->n", alphas.astype(jnp.float32),
-                      margins.astype(jnp.float32))
+                      margins.astype(jnp.float32), precision=_EXACT)
 
 
 def ensemble_vote_batched_ref(margins: jnp.ndarray, alphas: jnp.ndarray
@@ -56,7 +61,7 @@ def ensemble_vote_batched_ref(margins: jnp.ndarray, alphas: jnp.ndarray
     alphas: (B, T) -> (B, N) f32 ensemble margins.
     """
     return jnp.einsum("bt,btn->bn", alphas.astype(jnp.float32),
-                      margins.astype(jnp.float32))
+                      margins.astype(jnp.float32), precision=_EXACT)
 
 
 def stump_vote_batched_ref(xsel: jnp.ndarray, thr: jnp.ndarray,
@@ -71,7 +76,8 @@ def stump_vote_batched_ref(xsel: jnp.ndarray, thr: jnp.ndarray,
     m = (pol[:, :, None].astype(jnp.float32)
          * jnp.sign(xsel.astype(jnp.float32)
                     - thr[:, :, None].astype(jnp.float32) + 1e-12))
-    return jnp.einsum("bt,btn->bn", alphas.astype(jnp.float32), m)
+    return jnp.einsum("bt,btn->bn", alphas.astype(jnp.float32), m,
+                      precision=_EXACT)
 
 
 # Feature-fingerprint mixing constants, shared verbatim with the fused

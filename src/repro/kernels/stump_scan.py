@@ -1,14 +1,18 @@
 """Pallas TPU kernel: AdaBoost weighted-error sweep over the (feature x
 threshold) stump grid — the compute hot-spot of every boosting round.
 
-TPU adaptation (DESIGN.md §4): instead of the GPU one-thread-per-threshold
-mapping, the sample matrix is tiled into (block_n, F) VMEM blocks; each grid
-step broadcasts its block against the full (F, T) threshold grid on the VPU
-and accumulates the (F, T) weighted-error tile in the output block, which
-stays resident in VMEM across the sample-block grid (revisiting-output
-pattern).  F is padded to the 128-lane boundary by the ops wrapper.
+One kernel serves both the single-client fit and the stacked fleet fit:
+every operand carries a leading client axis (B = 1 for one client).  Each
+grid step takes a (block_b, block_n, F) sample block and accumulates the
+(block_b, T, F) weighted-error tile, which stays resident in VMEM across
+the sample-block axis (revisiting-output pattern).  Thresholds arrive
+transposed to (T, F) so features sit on the 128 lanes next to the sample
+block's; the sweep over the T thresholds is a static loop of 2-D compares
+and a sublane reduction, so no (block_n, F, T) intermediate exists and
+every sum is exact float32 on the VPU (an MXU contraction at default
+precision would round the weights to bfloat16 and move the argmin).
 
-    err[f, t] = sum_i w_i * [ sign(x[i,f] - thr[f,t]) != y_i ]
+    err[b, t, f] = sum_i w[b,i] * [ sign(x[b,i,f] - thr[b,t,f]) != y[b,i] ]
 """
 from __future__ import annotations
 
@@ -20,43 +24,42 @@ from jax.experimental import pallas as pl
 
 
 def _stump_kernel(x_ref, y_ref, w_ref, thr_ref, err_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         err_ref[...] = jnp.zeros_like(err_ref)
 
-    x = x_ref[...].astype(jnp.float32)          # (bn, F)
-    y = y_ref[...].astype(jnp.float32)          # (bn,)
-    w = w_ref[...].astype(jnp.float32)          # (bn,)
-    thr = thr_ref[...].astype(jnp.float32)      # (F, T)
+    x = x_ref[...].astype(jnp.float32)              # (bb, bn, F)
+    y = y_ref[...].astype(jnp.float32)              # (bb, bn, 1)
+    w = w_ref[...].astype(jnp.float32)              # (bb, bn, 1)
+    for t in range(thr_ref.shape[1]):
+        thr = thr_ref[:, t:t + 1, :].astype(jnp.float32)        # (bb, 1, F)
+        pred = jnp.where(x > thr, 1.0, -1.0)
+        miss = jnp.where(pred != y, w, 0.0)                     # (bb, bn, F)
+        err_ref[:, t:t + 1, :] += jnp.sum(miss, axis=1, keepdims=True)
 
-    pred = jnp.where(x[:, :, None] > thr[None, :, :], 1.0, -1.0)  # (bn,F,T)
-    miss = (pred != y[:, None, None]).astype(jnp.float32)
-    err_ref[...] += jnp.einsum(
-        "n,nft->ft", w, miss, preferred_element_type=jnp.float32)
 
-
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("block_b", "block_n", "interpret"))
 def stump_scan_kernel(x: jnp.ndarray, y: jnp.ndarray, w: jnp.ndarray,
-                      thresholds: jnp.ndarray, *, block_n: int = 256,
+                      thresholds_t: jnp.ndarray, *, block_b: int = 1,
+                      block_n: int = 256,
                       interpret: bool = True) -> jnp.ndarray:
-    """x: (N,F); y,w: (N,); thresholds: (F,T) -> (F,T) f32.
-    N must be a multiple of block_n (ops wrapper pads with w=0 rows)."""
-    N, F = x.shape
-    T = thresholds.shape[1]
-    assert N % block_n == 0, (N, block_n)
-    grid = (N // block_n,)
+    """x: (B,N,F); y, w: (B,N,1); thresholds_t: (B,T,F) -> (B,T,F) f32.
+    B and N must be multiples of block_b and block_n (the dispatch wrapper
+    pads with w = 0 rows and slots, which contribute nothing)."""
+    B, N, F = x.shape
+    T = thresholds_t.shape[1]
+    assert B % block_b == 0 and N % block_n == 0, (B, N, block_b, block_n)
+    col = pl.BlockSpec((block_b, block_n, 1), lambda b, i: (b, i, 0))
+    grid_tile = pl.BlockSpec((block_b, T, F), lambda b, i: (b, 0, 0))
     return pl.pallas_call(
         _stump_kernel,
-        grid=grid,
+        grid=(B // block_b, N // block_n),
         in_specs=[
-            pl.BlockSpec((block_n, F), lambda i: (i, 0)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((F, T), lambda i: (0, 0)),
+            pl.BlockSpec((block_b, block_n, F), lambda b, i: (b, i, 0)),
+            col, col, grid_tile,
         ],
-        out_specs=pl.BlockSpec((F, T), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((F, T), jnp.float32),
+        out_specs=grid_tile,
+        out_shape=jax.ShapeDtypeStruct((B, T, F), jnp.float32),
         interpret=interpret,
-    )(x, y, w, thresholds)
+    )(x, y, w, thresholds_t)

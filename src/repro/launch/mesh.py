@@ -12,15 +12,11 @@ Mesh shapes (TPU v5e pods):
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:                                 # jax >= 0.5
-    from jax.sharding import AxisType
 
-    def _axis_kwargs(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:                  # older jax: Auto is the only behaviour
-    def _axis_kwargs(n: int) -> dict:
-        return {}
+def _axis_kwargs(n: int) -> dict:
+    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False, data: int = 16,

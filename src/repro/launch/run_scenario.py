@@ -19,6 +19,7 @@ import argparse
 import sys
 
 from repro import obs
+from repro.launch.compile_cache import use_compile_cache
 from repro.sim.harness import run_scenario, summarize
 from repro.sim.scenarios import (SCENARIOS, base_scenarios, get_scenario,
                                  variant_scenarios)
@@ -81,6 +82,7 @@ def main() -> None:
     ap.add_argument("--metrics-out", default=None, metavar="OUT.json",
                     help="export the obs metrics-registry snapshot here")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.list_ or args.scenario is None:
         list_registry()

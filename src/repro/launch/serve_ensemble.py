@@ -32,6 +32,7 @@ from repro.sim.scenarios import DOMAINS
 from repro.core import FederatedBoostEngine
 from repro.data import make_domain_data
 from repro.kernels.dispatch import KernelPolicy
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve import (AutoscaleConfig, BatchConfig, FleetAutoscaler,
                          GossipConfig, PolicyTable, ServeMetrics,
                          ShardCluster, ShardedEnsembleServer)
@@ -179,6 +180,7 @@ def main() -> None:
     ap.add_argument("--metrics", default=None, metavar="OUT.json",
                     help="export the obs metrics-registry snapshot here")
     args = ap.parse_args()
+    use_compile_cache()
 
     tracer = None
     if args.trace or args.metrics:

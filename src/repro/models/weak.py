@@ -221,17 +221,18 @@ def get_weak_learner(name: str, n_thresholds: int = 16,
                      policy=None) -> WeakLearnerSpec:
     """``policy`` (a :class:`repro.kernels.KernelPolicy`) routes the stump
     scan through the kernel dispatcher, re-resolved per fit call so env or
-    calibration changes take effect without rebuilding the spec; ``None``
-    keeps the jnp oracle."""
+    calibration changes take effect without rebuilding the spec (each
+    resolution lands in ``policy.choices``); ``None`` keeps the jnp
+    oracle."""
     if name == "stump":
         def fit(x, y, w, key):
             thr = stump_thresholds(x, n_thresholds)
             if policy is None:
                 return fit_stump(x, y, w, thr)
             from repro.kernels import dispatch as kdispatch
-            backend = policy.resolve_name(
+            backend = policy.resolve(
                 "stump_scan", kdispatch.bucket_of("stump_scan",
-                                                  (x, y, w, thr)))
+                                                  (x, y, w, thr))).name
             return fit_stump(x, y, w, thr, backend=backend)
         return WeakLearnerSpec("stump", fit, predict_stump,
                                lambda p: STUMP_BYTES)

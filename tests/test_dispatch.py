@@ -59,6 +59,36 @@ def test_unavailable_backend_falls_through(monkeypatch):
         assert pol2.resolve_name("ensemble_vote", (8, 128)) == "interpret"
 
 
+def test_tpu_refuses_interpret_from_every_level(monkeypatch):
+    """On the TPU no resolution level may land on the interpreter: an
+    explicit argument, a forced policy, the env var and a table entry all
+    raise instead of falling through."""
+    from repro.kernels import dispatch
+    monkeypatch.delenv(ENV_VAR, raising=False)
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    bucket = (8, 128)
+    assert KernelPolicy().resolve_name("ensemble_vote", bucket) == "mosaic"
+    with pytest.raises(RuntimeError, match="cannot run on the TPU"):
+        KernelPolicy().resolve_name("ensemble_vote", bucket,
+                                    explicit="interpret")
+    with pytest.raises(RuntimeError, match="cannot run on the TPU"):
+        KernelPolicy(backend="interpret").resolve_name("ensemble_vote",
+                                                       bucket)
+    table = KernelPolicy(table={("ensemble_vote", bucket): "interpret"})
+    with pytest.raises(RuntimeError, match="cannot run on the TPU"):
+        table.resolve_name("ensemble_vote", bucket)
+    monkeypatch.setenv(ENV_VAR, "interpret")
+    with pytest.raises(RuntimeError, match=ENV_VAR):
+        KernelPolicy().resolve("ensemble_vote", bucket)
+
+
+def test_tpu_calibration_skips_interpret(monkeypatch):
+    from repro.kernels import dispatch
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    assert "interpret" not in dispatch.available_backends()
+    assert dispatch.available_backends() == ["mosaic", "xla"]
+
+
 def test_env_change_takes_effect_without_rebuild(monkeypatch):
     """The dispatch cache must never pin a stale env-driven choice."""
     monkeypatch.delenv(ENV_VAR, raising=False)
@@ -202,7 +232,8 @@ def test_save_records_measuring_platform(tmp_path):
 
 
 def test_cross_platform_table_warns_exactly_once(tmp_path):
-    from repro.kernels import dispatch
+    """A tuned table measured on another platform is refused at load
+    time, every time, rather than steering this platform's dispatch."""
     here = jax.default_backend()
     other = "tpu" if here != "tpu" else "gpu"
     p = tmp_path / "cal_other.json"
@@ -210,16 +241,9 @@ def test_cross_platform_table_warns_exactly_once(tmp_path):
         "version": 2, "backend": None, "measured_on": other,
         "table": [{"kernel": "ensemble_vote", "bucket": [8, 128],
                    "backend": "xla", "layout": {}}]}))
-    dispatch._PLATFORM_WARNED.discard((other, here))
-    with pytest.warns(RuntimeWarning, match=f"measured on '{other}'"):
-        loaded = KernelPolicy.load(str(p))
-    assert loaded.measured_on == other
-    assert loaded.resolve_name("ensemble_vote", (8, 128)) == "xla"
-    # one-shot per (measured_on, platform) pair: a reload stays silent
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        KernelPolicy.load(str(p))
-    dispatch._PLATFORM_WARNED.discard((other, here))
+    for _ in range(2):
+        with pytest.raises(ValueError, match=f"measured on '{other}'"):
+            KernelPolicy.load(str(p))
 
 
 def test_same_platform_and_empty_tables_load_silently(tmp_path):

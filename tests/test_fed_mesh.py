@@ -12,8 +12,7 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json, dataclasses
-    import jax, jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P, NamedSharding
+    import jax
     from repro.configs.paper_fedboost import FedBoostConfig
     from repro.sim.scenarios import DOMAINS
     from repro.core import fed_mesh
@@ -22,27 +21,17 @@ SCRIPT = textwrap.dedent("""
 
     K = 8
     dom = dataclasses.replace(DOMAINS['edge_vision'], n_clients=K)
-    data = make_domain_data(dom, seed=0)
-    n_local = min(c[0].shape[0] for c in data['clients'])
-    x = jnp.stack([c[0][:n_local] for c in data['clients']])
-    y = jnp.stack([c[1][:n_local] for c in data['clients']])
-    xv_full, yv_full = data['val']
-    nvl = xv_full.shape[0] // K
-    xv = xv_full[:K*nvl].reshape(K, nvl, -1)
-    yv = yv_full[:K*nvl].reshape(K, nvl)
+    x, y, xv, yv = fed_mesh.pack_clients(make_domain_data(dom, seed=0), K)
 
-    mesh = jax.make_mesh((K,), ("clients",))
+    mesh = fed_mesh.client_mesh(jax.devices()[:K])
     cfg = FedBoostConfig(n_clients=K)
     thr = stump_thresholds(x.reshape(-1, x.shape[-1]))
     step = fed_mesh.make_fed_boost_step(cfg, mesh, "clients", thr)
-    state = fed_mesh.init_state(cfg, K, n_local, nvl, buffer_cap=8,
-                                ens_cap=1024, key=jax.random.key(0))
-    sh = jax.tree.map(lambda s: NamedSharding(mesh, s),
-                      fed_mesh.state_shardings(mesh, "clients"),
-                      is_leaf=lambda v: isinstance(v, P))
-    dsh = NamedSharding(mesh, P("clients"))
-    state = jax.device_put(state, sh)
-    x, y, xv, yv = (jax.device_put(a, dsh) for a in (x, y, xv, yv))
+    state = fed_mesh.init_state(cfg, K, x.shape[1], xv.shape[1],
+                                buffer_cap=8, ens_cap=1024,
+                                key=jax.random.key(0))
+    state, x, y, xv, yv = fed_mesh.place(mesh, "clients", state,
+                                         x, y, xv, yv)
     jstep = jax.jit(step, donate_argnums=0)
     intervals = []
     for r in range(40):
