@@ -420,8 +420,10 @@ class FederatedBoostEngine:
     def run(self) -> RunMetrics:
         if self._fleet:
             from repro.core.fleet import FleetCore
-            FleetCore(self).run()
-        elif self.engine_kind == "events":
+            with obs.span("train.fleet.run"):
+                FleetCore(self).run()          # finalizes the metrics
+            return self.metrics
+        if self.engine_kind == "events":
             if self.mode == "baseline":
                 self._run_baseline_events()
             else:

@@ -36,6 +36,15 @@ exactly like the reference.
 
 Only the ``stump`` weak learner is supported — the batched launch path is
 stump-specific (the other learners never run at fleet scale).
+
+Tracing is per phase, never per client: a job is one ``train.fleet.run``
+span holding the shard stacking, the threshold grids, the first timing
+walk, each fit wave (``train.fit_batch``: gather, transfer, fit) and its
+local update, one ``train.fleet.sync`` per arrival, and the finish; the
+``train.fleet.*`` counters add the bytes handed to the device and copied
+on the host, and the slots and rows the waves launch.  With
+``obs.tracing(profiler=True)`` the spans land in a ``jax.profiler`` trace
+beside the device ops, so the device's idle time can be split by phase.
 """
 from __future__ import annotations
 
@@ -78,26 +87,28 @@ class FleetCore:
         B = len(self.clients)
 
         # ---- stacked, padded shards (pad rows: x=0, y=0, D=0) ----
-        self.n_valid = np.array([c.x.shape[0] for c in self.clients],
-                                np.int32)
-        N = int(self.n_valid.max())
-        F = int(np.asarray(self.clients[0].x).shape[1])
-        self.X = np.zeros((B, N, F), _F32)
-        self.Y = np.zeros((B, N), _F32)
-        self.D = np.zeros((B, N), _F32)
-        for b, c in enumerate(self.clients):
-            n = int(self.n_valid[b])
-            self.X[b, :n] = np.asarray(c.x, _F32)
-            self.Y[b, :n] = np.asarray(c.y, _F32)
-            yb = self.Y[b, :n]
-            if self.cfg.balanced_init:
-                pos = (yb > 0).astype(_F32)
-                npos = max(float(pos.sum()), 1.0)
-                nneg = max(n - float(pos.sum()), 1.0)
-                self.D[b, :n] = pos / (2 * npos) + (1 - pos) / (2 * nneg)
-            else:
-                self.D[b, :n] = 1.0 / n
-        self.THR = self._build_thresholds()                    # (B, F, T)
+        with obs.span("train.fleet.stack"):
+            self.n_valid = np.array([c.x.shape[0] for c in self.clients],
+                                    np.int32)
+            N = int(self.n_valid.max())
+            F = int(np.asarray(self.clients[0].x).shape[1])
+            self.X = np.zeros((B, N, F), _F32)
+            self.Y = np.zeros((B, N), _F32)
+            self.D = np.zeros((B, N), _F32)
+            for b, c in enumerate(self.clients):
+                n = int(self.n_valid[b])
+                self.X[b, :n] = np.asarray(c.x, _F32)
+                self.Y[b, :n] = np.asarray(c.y, _F32)
+                yb = self.Y[b, :n]
+                if self.cfg.balanced_init:
+                    pos = (yb > 0).astype(_F32)
+                    npos = max(float(pos.sum()), 1.0)
+                    nneg = max(n - float(pos.sum()), 1.0)
+                    self.D[b, :n] = pos / (2 * npos) + (1 - pos) / (2 * nneg)
+                else:
+                    self.D[b, :n] = 1.0 / n
+        with obs.span("train.fleet.thresholds"):
+            self.THR = self._build_thresholds()                # (B, F, T)
 
         # ---- server state mirrors (numpy-side ensemble view) ----
         xv, yv = eng.data["val"]
@@ -122,8 +133,16 @@ class FleetCore:
                              + ENTRY_OVERHEAD_BYTES)
 
     # ------------------------------------------------------------ batched fits
-    def _build_thresholds(self) -> np.ndarray:
+    @staticmethod
+    def _to_device(*arrays):
+        """Hand host arrays to the device, counting their bytes."""
         import jax.numpy as jnp
+        with obs.span("train.fleet.h2d"):
+            obs.count("train.fleet.h2d_bytes",
+                      sum(a.nbytes for a in arrays))
+            return tuple(jnp.asarray(a) for a in arrays)
+
+    def _build_thresholds(self) -> np.ndarray:
         from repro.models.weak import stump_thresholds_batched
         B = self.X.shape[0]
         chunk = min(THRESHOLD_CHUNK, _next_pow2(B))
@@ -133,12 +152,17 @@ class FleetCore:
             nb = self.n_valid[lo:lo + chunk]
             pad = chunk - xb.shape[0]
             if pad:
-                xb = np.concatenate([xb, np.zeros(
-                    (pad,) + xb.shape[1:], _F32)])
-                nb = np.concatenate([nb, np.ones(pad, np.int32)])
-            g = stump_thresholds_batched(jnp.asarray(xb), jnp.asarray(nb))
-            grids.append(np.asarray(g, _F32)[:xb.shape[0] - pad
-                                             if pad else None])
+                with obs.span("train.fleet.gather"):
+                    xb = np.concatenate([xb, np.zeros(
+                        (pad,) + xb.shape[1:], _F32)])
+                    nb = np.concatenate([nb, np.ones(pad, np.int32)])
+                    obs.count("train.fleet.gather_bytes",
+                              xb.nbytes + nb.nbytes)
+            args = self._to_device(xb, nb)
+            with obs.span("train.fleet.grid_wait"):
+                g = stump_thresholds_batched(*args)
+                grids.append(np.asarray(g, _F32)[:xb.shape[0] - pad
+                                                 if pad else None])
         return np.concatenate(grids)[:B]
 
     def _fit_backend(self, xb) -> Optional[str]:
@@ -163,27 +187,33 @@ class FleetCore:
                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """One bucketed batched-fit launch over ``slots`` (client rows),
         padded to a power of two with zero-weight slots."""
-        import jax.numpy as jnp
         from repro.models.weak import fit_stump_batched
         Bw = len(slots)
         BP = max(8, _next_pow2(Bw))
         pad = BP - Bw
-        xb, yb = self.X[slots], self.Y[slots]
-        wb, tb = self.D[slots], self.THR[slots]
-        if pad:
-            z = lambda a: np.concatenate(
-                [a, np.zeros((pad,) + a.shape[1:], a.dtype)])
-            xb, yb, wb, tb = z(xb), z(yb), z(wb), z(tb)
         with obs.span("train.fit_batch", n_slots=Bw, padded=BP):
-            args = (jnp.asarray(xb), jnp.asarray(yb),
-                    jnp.asarray(wb), jnp.asarray(tb))
-            params = fit_stump_batched(*args,
-                                       backend=self._fit_backend(args))
+            with obs.span("train.fleet.gather"):
+                ops = [self.X[slots], self.Y[slots],
+                       self.D[slots], self.THR[slots]]
+                staged = sum(a.nbytes for a in ops)
+                if pad:
+                    ops = [np.concatenate(
+                        [a, np.zeros((pad,) + a.shape[1:], a.dtype)])
+                        for a in ops]
+                    staged += sum(a.nbytes for a in ops)
+                obs.count("train.fleet.gather_bytes", staged)
+            args = self._to_device(*ops)
+            with obs.span("train.fleet.fit"):
+                params = fit_stump_batched(*args,
+                                           backend=self._fit_backend(args))
+                f = np.asarray(params["feature"])[:Bw].astype(np.int64)
+                thr = np.asarray(params["threshold"], _F32)[:Bw]
+                pol = np.asarray(params["polarity"], _F32)[:Bw]
         obs.count("train.fit_batches")
         obs.count("train.fits", Bw)
-        f = np.asarray(params["feature"])[:Bw].astype(np.int64)
-        thr = np.asarray(params["threshold"], _F32)[:Bw]
-        pol = np.asarray(params["polarity"], _F32)[:Bw]
+        obs.count("train.fleet.slots_launched", BP)
+        obs.count("train.fleet.rows_launched", BP * self.X.shape[1])
+        obs.count("train.fleet.real_rows", int(self.n_valid[slots].sum()))
         return f, thr, pol
 
     def _local_update(self, slots: np.ndarray, f: np.ndarray,
@@ -192,17 +222,21 @@ class FleetCore:
         """Vectorized mirror of the reference ``_train_one`` tail: eps on
         the pre-update distribution, the local alpha, and the eq.-(4)
         distribution update, for every fitted slot at once."""
-        xsel = np.take_along_axis(
-            self.X[slots], f[:, None, None], axis=2)[:, :, 0]
-        h = pol[:, None] * np.sign(xsel - thr[:, None] + 1e-12)
-        yb, Db = self.Y[slots], self.D[slots]
-        pred = np.where(h > 0, 1.0, -1.0).astype(_F32)
-        eps = np.sum(Db * (pred != yb), axis=1, dtype=_F32)
-        epsc = np.clip(eps, 1e-6, 1.0 - 1e-6)
-        alpha = (0.5 * np.log((1.0 - epsc) / epsc)).astype(_F32)
-        w = Db * np.exp(-alpha[:, None] * yb * h)
-        Z = np.sum(w, axis=1, dtype=_F32)
-        self.D[slots] = w / (Z[:, None] + 1e-30)
+        with obs.span("train.fleet.local_update"):
+            with obs.span("train.fleet.gather"):
+                xb, yb, Db = self.X[slots], self.Y[slots], self.D[slots]
+                obs.count("train.fleet.gather_bytes",
+                          xb.nbytes + yb.nbytes + Db.nbytes)
+            xsel = np.take_along_axis(xb, f[:, None, None], axis=2)[:, :, 0]
+            del xb
+            h = pol[:, None] * np.sign(xsel - thr[:, None] + 1e-12)
+            pred = np.where(h > 0, 1.0, -1.0).astype(_F32)
+            eps = np.sum(Db * (pred != yb), axis=1, dtype=_F32)
+            epsc = np.clip(eps, 1e-6, 1.0 - 1e-6)
+            alpha = (0.5 * np.log((1.0 - epsc) / epsc)).astype(_F32)
+            w = Db * np.exp(-alpha[:, None] * yb * h)
+            Z = np.sum(w, axis=1, dtype=_F32)
+            self.D[slots] = w / (Z[:, None] + 1e-30)
         return eps, alpha
 
     def _defer_fit(self, c) -> BufferEntry:
@@ -369,15 +403,17 @@ class FleetCore:
 
     # ---------------------------------------------------------------- run
     def run(self) -> None:
+        """Run the job and finalize the engine's metrics."""
         if self.m.mode == "baseline":
             self._run_baseline()
         else:
             self._run_enhanced()
-        # hand the accumulated margins back so the engine's _finalize /
-        # _val_error see the fleet-computed state
-        import jax.numpy as jnp
-        self.eng._val_margin = jnp.asarray(self.Mval)
-        self.eng._test_margin = jnp.asarray(self.Mtest)
+        with obs.span("train.fleet.finish"):
+            # hand the accumulated margins back so the engine's _finalize
+            # / _val_error see the fleet-computed state
+            self.eng._val_margin, self.eng._test_margin = self._to_device(
+                self.Mval, self.Mtest)
+            self.eng._finalize()
 
     def _run_baseline(self) -> None:
         """Synchronous baseline, fleet profile.  Same TRIGGER/BARRIER
@@ -401,48 +437,52 @@ class FleetCore:
                 late, pending_late = pending_late, []
                 on_time: List[Tuple[int, BufferEntry]] = []
                 durations: List[float] = []
-                for b, c in enumerate(self.clients):
-                    dropped = not c.behavior.availability(t0)
-                    dur = c.behavior.compute_time(eng.BASE_ROUND_S, t0)
-                    e = BufferEntry(
-                        {"feature": int(f[b]), "threshold": float(thr[b]),
-                         "polarity": float(pol[b])},
-                        float(eps[b]), float(alpha[b]), c.local_round)
-                    c.local_round += 1
-                    if dropped:
-                        m.rounds_unavailable += 1
-                        pending_late.append((b, e))
-                        continue
-                    up = self._entry_bytes + cfg.header_bytes
-                    m.uplink_bytes += up
-                    m.n_messages += 1
-                    durations.append(
-                        dur + c.behavior.link(t0).tx_time(up))
-                    on_time.append((b, e))
+                with obs.span("train.fleet.walk"):
+                    for b, c in enumerate(self.clients):
+                        dropped = not c.behavior.availability(t0)
+                        dur = c.behavior.compute_time(eng.BASE_ROUND_S, t0)
+                        e = BufferEntry(
+                            {"feature": int(f[b]),
+                             "threshold": float(thr[b]),
+                             "polarity": float(pol[b])},
+                            float(eps[b]), float(alpha[b]), c.local_round)
+                        c.local_round += 1
+                        if dropped:
+                            m.rounds_unavailable += 1
+                            pending_late.append((b, e))
+                            continue
+                        up = self._entry_bytes + cfg.header_bytes
+                        m.uplink_bytes += up
+                        m.n_messages += 1
+                        durations.append(
+                            dur + c.behavior.link(t0).tx_time(up))
+                        on_time.append((b, e))
                 close = t0 + (max(durations) if durations
                               else eng.BASE_ROUND_S)
                 vc.push(close, events.BARRIER, payload=(r, late, on_time))
             elif ev.kind == events.BARRIER:
                 r, late, on_time = ev.payload
                 t = ev.t
-                for cid, e in late:
-                    m.uplink_bytes += self._entry_bytes + cfg.header_bytes
-                    m.n_messages += 1
-                w0 = len(self._lf)
-                batch = late + on_time
-                self._merge_window([e for _, e in batch],
-                                   [cid for cid, _ in batch],
-                                   sync_round=r, compensated=False)
-                delta = len(self._lf) - w0
-                pkg = delta * 16 + cfg.header_bytes
-                m.downlink_bytes += B * pkg
-                m.n_messages += B
-                self._catch_up_fleet(w0, len(self._lf))
-                m.n_syncs += 1
-                obs.count("train.syncs")
-                obs.count("train.learners_merged", delta)
-                eng._maybe_publish(t)
-                eng._record(t, err=self._val_err())
+                with obs.span("train.fleet.sync"):
+                    for cid, e in late:
+                        m.uplink_bytes += (self._entry_bytes
+                                           + cfg.header_bytes)
+                        m.n_messages += 1
+                    w0 = len(self._lf)
+                    batch = late + on_time
+                    self._merge_window([e for _, e in batch],
+                                       [cid for cid, _ in batch],
+                                       sync_round=r, compensated=False)
+                    delta = len(self._lf) - w0
+                    pkg = delta * 16 + cfg.header_bytes
+                    m.downlink_bytes += B * pkg
+                    m.n_messages += B
+                    self._catch_up_fleet(w0, len(self._lf))
+                    m.n_syncs += 1
+                    obs.count("train.syncs")
+                    obs.count("train.learners_merged", delta)
+                    eng._maybe_publish(t)
+                    eng._record(t, err=self._val_err())
                 if r + 1 < cfg.n_rounds:
                     vc.push(t, events.TRIGGER, payload=r + 1)
         obs.count("train.events", vc.n_popped)
@@ -456,20 +496,21 @@ class FleetCore:
         cfg, m = self.cfg, self.m
         if not pending_late:
             return t
-        t_flush = t
-        for cid, e in pending_late:
-            c = self.clients[cid]
-            up = self._entry_bytes + cfg.header_bytes
-            m.uplink_bytes += up
-            m.n_messages += 1
-            t_flush = max(t_flush, t + c.behavior.link(t).tx_time(up))
-        self._merge_window([e for _, e in pending_late],
-                           [cid for cid, _ in pending_late],
-                           sync_round=cfg.n_rounds, compensated=False)
-        if obs.enabled():
-            obs.point("train.late_flush", sim_t0=t_flush,
-                      n=len(pending_late))
-        self.eng._record(t_flush, err=self._val_err())
+        with obs.span("train.fleet.sync"):
+            t_flush = t
+            for cid, e in pending_late:
+                c = self.clients[cid]
+                up = self._entry_bytes + cfg.header_bytes
+                m.uplink_bytes += up
+                m.n_messages += 1
+                t_flush = max(t_flush, t + c.behavior.link(t).tx_time(up))
+            self._merge_window([e for _, e in pending_late],
+                               [cid for cid, _ in pending_late],
+                               sync_round=cfg.n_rounds, compensated=False)
+            if obs.enabled():
+                obs.point("train.late_flush", sim_t0=t_flush,
+                          n=len(pending_late))
+            self.eng._record(t_flush, err=self._val_err())
         return t_flush
 
     def _run_enhanced(self) -> None:
@@ -486,24 +527,17 @@ class FleetCore:
         finished = [False] * len(self.clients)
 
         def advance(c) -> None:
-            trace = obs.enabled()
             while c.local_round < cfg.n_rounds:
                 dropped = not c.behavior.availability(c.clock)
                 e = self._defer_fit(c)
                 c.clock += c.behavior.compute_time(eng.BASE_ROUND_S,
                                                    c.clock)
-                if trace:
-                    vc.push(c.clock, events.ROUND, c.cid)
                 c.buffer.entries.append(e)
                 if dropped:
                     m.rounds_unavailable += 1
                     c.clock += c.behavior.stall_time(eng.BASE_ROUND_S,
                                                      c.clock)
-                    if trace:
-                        vc.push(c.clock, events.STALL, c.cid)
                 if len(c.buffer) >= c.known_interval:
-                    if trace:
-                        vc.push(c.clock, events.TRIGGER, c.cid)
                     arrival, payload = self._prepare_sync(c)
                     vc.push(arrival, events.ARRIVAL, c.cid, payload)
                     return
@@ -512,44 +546,37 @@ class FleetCore:
                 arrival, payload = self._prepare_sync(c)
                 vc.push(arrival, events.ARRIVAL, c.cid, payload)
 
-        for c in self.clients:
-            advance(c)
+        with obs.span("train.fleet.walk"):
+            for c in self.clients:
+                advance(c)
         t = 0.0
         interval_gauge = obs.get_registry().gauge("train.interval")
         while vc:
             ev = vc.pop()
-            if ev.kind == events.ROUND:
-                obs.point("train.client_round", sim_t0=ev.t, cid=ev.cid)
-                continue
-            if ev.kind == events.STALL:
-                obs.point("train.stall", sim_t0=ev.t, cid=ev.cid)
-                continue
-            if ev.kind == events.TRIGGER:
-                obs.point("train.trigger", sim_t0=ev.t, cid=ev.cid)
-                continue
             t, cid, payload = ev.t, ev.cid, ev.payload
             if any(e.params is None for e in payload):
                 self._resolve_pending()
-            c = self.clients[cid]
-            sync_round = c.local_round - 1
-            self._merge_window(payload, [cid] * len(payload),
-                               sync_round=sync_round, compensated=True)
-            m.n_syncs += 1
-            obs.count("train.syncs")
-            obs.count("train.learners_merged", len(payload))
-            err = self._val_err()
-            eng.scheduler.observe(err)
-            delta = len(self._lf) - c.last_merged_idx
-            pkg = delta * 16 + cfg.header_bytes
-            m.downlink_bytes += pkg
-            m.n_messages += 1
-            self._catch_up_client(c)
-            c.known_interval = eng.scheduler.current
-            interval_gauge.set(eng.scheduler.current)
-            eng._maybe_publish(t)
-            eng._record(t, err=err)
-            if not finished[cid]:
-                advance(c)
+            with obs.span("train.fleet.sync"):
+                c = self.clients[cid]
+                sync_round = c.local_round - 1
+                self._merge_window(payload, [cid] * len(payload),
+                                   sync_round=sync_round, compensated=True)
+                m.n_syncs += 1
+                obs.count("train.syncs")
+                obs.count("train.learners_merged", len(payload))
+                err = self._val_err()
+                eng.scheduler.observe(err)
+                delta = len(self._lf) - c.last_merged_idx
+                pkg = delta * 16 + cfg.header_bytes
+                m.downlink_bytes += pkg
+                m.n_messages += 1
+                self._catch_up_client(c)
+                c.known_interval = eng.scheduler.current
+                interval_gauge.set(eng.scheduler.current)
+                eng._maybe_publish(t)
+                eng._record(t, err=err)
+                if not finished[cid]:
+                    advance(c)
         obs.count("train.events", vc.n_popped)
         m.sim_time_s = max(t, max(c.clock for c in self.clients))
 

@@ -7,7 +7,8 @@ helpers the instrumented subsystems call unconditionally:
   :func:`span`/:func:`point` return the shared :data:`NULL_SPAN` without
   allocating.  Enable with :func:`configure` (CLIs) or the :func:`tracing`
   context manager (tests, harness runs), which installs a fresh
-  :class:`Tracer` and restores the previous state on exit.
+  :class:`Tracer` and restores the previous state on exit.  Either takes
+  ``profiler=True`` to mirror spans into the ``jax.profiler`` trace.
 * the **metrics registry** (:mod:`repro.obs.registry`): always available
   via :func:`get_registry` (counters are a dict hit + float add).  The
   expensive recorders — kernel-launch wall timing in
@@ -60,16 +61,19 @@ def profiling_enabled() -> bool:
 
 def configure(trace: bool = True, ring: int = 65536,
               profile_kernels: Optional[bool] = None,
-              registry: Optional[MetricsRegistry] = None) -> Optional[Tracer]:
+              registry: Optional[MetricsRegistry] = None,
+              profiler: bool = False) -> Optional[Tracer]:
     """Install (or tear down) the process-wide observability state.
 
     ``trace=True`` installs a fresh :class:`Tracer` with a ``ring``-bounded
     span buffer; ``trace=False`` disables tracing.  ``profile_kernels``
     defaults to following ``trace``.  ``registry`` swaps the global
-    metrics registry (a fresh one isolates a run's counters).  Returns the
-    active tracer (None when disabled)."""
+    metrics registry (a fresh one isolates a run's counters).
+    ``profiler=True`` mirrors every span into the ``jax.profiler`` trace
+    (see :class:`Tracer`).  Returns the active tracer (None when
+    disabled)."""
     global _TRACER, _PROFILE, _REGISTRY
-    _TRACER = Tracer(ring) if trace else None
+    _TRACER = Tracer(ring, profiler=profiler) if trace else None
     _PROFILE = trace if profile_kernels is None else bool(profile_kernels)
     if registry is not None:
         _REGISTRY = registry
@@ -85,14 +89,18 @@ def disable() -> None:
 
 @contextlib.contextmanager
 def tracing(ring: int = 65536, profile_kernels: Optional[bool] = None,
-            fresh_registry: bool = True) -> Iterator[Tracer]:
+            fresh_registry: bool = True,
+            profiler: bool = False) -> Iterator[Tracer]:
     """Scoped tracing: install a fresh tracer (and, by default, a fresh
     metrics registry so the scope's counters are isolated), yield it, and
     restore the previous global state on exit — exception-safe, so a test
-    or harness run can never leak an enabled tracer into the process."""
+    or harness run can never leak an enabled tracer into the process.
+    ``profiler=True`` mirrors every span into the ``jax.profiler`` trace;
+    pair it with ``profile_kernels=False``, since the blocking kernel
+    timers would change what the device trace shows."""
     global _TRACER, _PROFILE, _REGISTRY
     prev = (_TRACER, _PROFILE, _REGISTRY)
-    tracer = Tracer(ring)
+    tracer = Tracer(ring, profiler=profiler)
     _TRACER = tracer
     _PROFILE = True if profile_kernels is None else bool(profile_kernels)
     if fresh_registry:

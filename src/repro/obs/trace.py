@@ -42,6 +42,12 @@ its child (one implicit stack per tracer; the tree is validated by
 ``repro.launch.obs_report --check``).  The tracer is deliberately
 single-threaded — everything in this repo advances a simulated clock from
 one thread; a threaded ingress would hold one tracer per worker.
+
+A tracer made with ``profiler=True`` also opens a
+``jax.profiler.TraceAnnotation`` of each span's name for as long as the
+span is open, so a ``jax.profiler`` trace holds the spans on its host
+line, on the profiler's clock: a device idle gap can then be put down to
+the span the host was in.  Points are instants and are not mirrored.
 """
 from __future__ import annotations
 
@@ -84,7 +90,7 @@ class Span:
 
     __slots__ = ("name", "span_id", "parent_id", "trace_id", "host",
                  "links", "t0", "t1", "sim_t0", "sim_t1", "attrs",
-                 "_tracer")
+                 "_tracer", "_mirror")
 
     def __init__(self, tracer: "Tracer", name: str, span_id: int,
                  parent_id: Optional[int], sim_t: Optional[float],
@@ -102,6 +108,7 @@ class Span:
         self.sim_t0 = None if sim_t is None else float(sim_t)
         self.sim_t1: Optional[float] = None
         self.attrs = attrs
+        self._mirror = None           # open profiler annotation, if any
 
     # ------------------------------------------------------------- surface
     @property
@@ -129,7 +136,13 @@ class Span:
         if sim_t is not None:
             self.sim_t1 = float(sim_t)
         self.t1 = time.perf_counter()
+        self._close_mirror()
         self._tracer._finish(self)
+
+    def _close_mirror(self) -> None:
+        if self._mirror is not None:
+            self._mirror.__exit__(None, None, None)
+            self._mirror = None
 
     def __enter__(self) -> "Span":
         return self
@@ -185,9 +198,18 @@ class Tracer:
     ``ring`` bounds memory: a long soak keeps the most recent spans and
     drops the oldest (dropped count in :attr:`dropped` — surfaced by the
     export meta line so a truncated ring is never read as complete).
+
+    ``profiler=True`` mirrors every span (not points, which are instants)
+    into ``jax.profiler.TraceAnnotation`` of the same name, opened and
+    closed with it, so a profiler trace shows the spans on its host line,
+    on the same clock as the device ops.
     """
 
-    def __init__(self, ring: int = 65536):
+    def __init__(self, ring: int = 65536, profiler: bool = False):
+        self._annotation = None
+        if profiler:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
         self._ring: deque = deque(maxlen=int(ring))
         self._stack: List[Span] = []       # open spans (nesting)
         self._ids = itertools.count(1)
@@ -225,6 +247,9 @@ class Tracer:
                   parent.span_id if parent is not None else None,
                   sim_t, attrs, trace_id=tid, host=hid,
                   links=_norm_links(ctx, link))
+        if self._annotation is not None:
+            sp._mirror = self._annotation(name)
+            sp._mirror.__enter__()
         self._stack.append(sp)
         self.started += 1
         return sp
@@ -251,7 +276,7 @@ class Tracer:
         # early exit are abandoned rather than corrupting later parents
         if any(s is sp for s in self._stack):
             while self._stack and self._stack[-1] is not sp:
-                self._stack.pop()
+                self._stack.pop()._close_mirror()
             if self._stack:
                 self._stack.pop()
         if len(self._ring) == self._ring.maxlen:

@@ -57,6 +57,66 @@ def test_disabled_span_is_shared_noop():
     assert not obs.profiling_enabled()
 
 
+def test_disabled_span_stays_noop_after_a_profiler_mirror():
+    with obs.tracing(profile_kernels=False, profiler=True):
+        assert obs.span("on") is not obs.NULL_SPAN
+    assert obs.span("x", sim_t=1.0) is obs.NULL_SPAN
+    assert obs.configure(trace=False, profiler=True) is None
+    try:
+        assert obs.span("x") is obs.NULL_SPAN
+        assert obs.point("y") is obs.NULL_SPAN
+    finally:
+        obs.disable()
+
+
+def _host_events(trace_dir):
+    """Names of every event of the trace at ``trace_dir``."""
+    from pathlib import Path
+
+    from jax.profiler import ProfileData
+    path = sorted(Path(trace_dir).rglob("*.xplane.pb"))[-1]
+    pd = ProfileData.from_file(str(path))
+    return [ev.name for plane in pd.planes for line in plane.lines
+            for ev in line.events]
+
+
+def test_profiler_mirror_puts_spans_in_the_device_trace(tmp_path):
+    """With ``profiler=True`` a span shows up by name among the host
+    events of the ``jax.profiler`` trace, an abandoned child closing with
+    its parent; points (instants) are not mirrored, and a tracer without
+    the mirror adds nothing."""
+    import jax
+    import jax.numpy as jnp
+    jax.profiler.start_trace(str(tmp_path / "on"))
+    try:
+        with obs.tracing(profile_kernels=False, profiler=True) as tr:
+            with obs.span("mirror.outer"):
+                with obs.span("mirror.inner"):
+                    jnp.ones(8).sum().block_until_ready()
+                obs.point("mirror.instant")
+            with obs.span("mirror.parent"):
+                obs.span("mirror.abandoned")   # closes with its parent
+            spans = tr.finished()
+    finally:
+        jax.profiler.stop_trace()
+    names = _host_events(tmp_path / "on")
+    for name in ("mirror.outer", "mirror.inner", "mirror.parent",
+                 "mirror.abandoned"):
+        assert names.count(name) == 1, name
+    assert "mirror.instant" not in names
+    assert {d["name"] for d in spans} == {"mirror.outer", "mirror.inner",
+                                          "mirror.instant", "mirror.parent"}
+
+    jax.profiler.start_trace(str(tmp_path / "off"))
+    try:
+        with obs.tracing(profile_kernels=False):
+            with obs.span("plain.span"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    assert "plain.span" not in _host_events(tmp_path / "off")
+
+
 def test_tracing_scope_restores_previous_state():
     assert not obs.enabled()
     reg_before = obs.get_registry()
