@@ -11,6 +11,13 @@ profile keeps the *same event-queue semantics* but restructures the math:
   mass, which every batched kernel treats as "contributes nothing".  The
   per-client quantile threshold grids come from one
   ``stump_thresholds_batched`` launch at construction.
+* **Resident wave staging.**  A fleet that fits one threshold chunk keeps
+  the chunk's rows and grids where the grid launch put them, on the device.
+  A fit wave of every client in order, padded to that chunk, reads them
+  there and stages only its labels and weights from the host; every other
+  wave (partial, permuted, a fleet of several chunks or of fewer than 8
+  clients) gathers and pads its four operands on the host.  The local
+  update gathers only the fitted feature column of each slot.
 * **Deferred, batched fits.**  A client leg between syncs is causally
   closed, so its *timing* walk (availability/compute/stall/link draws — the
   behavior calls, in the reference call order) runs eagerly while the stump
@@ -42,9 +49,10 @@ span holding the shard stacking, the threshold grids, the first timing
 walk, each fit wave (``train.fit_batch``: gather, transfer, fit) and its
 local update, one ``train.fleet.sync`` per arrival, and the finish; the
 ``train.fleet.*`` counters add the bytes handed to the device and copied
-on the host, and the slots and rows the waves launch.  With
-``obs.tracing(profiler=True)`` the spans land in a ``jax.profiler`` trace
-beside the device ops, so the device's idle time can be split by phase.
+on the host, the slots and rows the waves launch, and the waves that
+read the resident stack.  With ``obs.tracing(profiler=True)`` the spans
+land in a ``jax.profiler`` trace beside the device ops, so the device's
+idle time can be split by phase.
 """
 from __future__ import annotations
 
@@ -143,9 +151,13 @@ class FleetCore:
             return tuple(jnp.asarray(a) for a in arrays)
 
     def _build_thresholds(self) -> np.ndarray:
+        """The host grids, chunk by chunk.  A fleet of one chunk keeps
+        that chunk's device rows and grids as ``_Xd`` / ``_THRd`` (pad
+        slots included) for the fit waves that read them in place."""
         from repro.models.weak import stump_thresholds_batched
         B = self.X.shape[0]
         chunk = min(THRESHOLD_CHUNK, _next_pow2(B))
+        self._Xd = self._THRd = None
         grids = []
         for lo in range(0, B, chunk):
             xb = self.X[lo:lo + chunk]
@@ -163,6 +175,8 @@ class FleetCore:
                 g = stump_thresholds_batched(*args)
                 grids.append(np.asarray(g, _F32)[:xb.shape[0] - pad
                                                  if pad else None])
+        if B <= chunk:
+            self._Xd, self._THRd = args[0], g
         return np.concatenate(grids)[:B]
 
     def _fit_backend(self, xb) -> Optional[str]:
@@ -183,18 +197,27 @@ class FleetCore:
             policy.choices[("stump_scan_batched", bucket)] = name
         return name
 
+    def _reads_resident(self, slots: np.ndarray, BP: int) -> bool:
+        """Whether a wave over ``slots`` padded to ``BP`` is exactly the
+        resident chunk: every client, in order, with the chunk's pad."""
+        return (self._Xd is not None and self._Xd.shape[0] == BP
+                and np.array_equal(slots, np.arange(self.X.shape[0])))
+
     def _fit_wave(self, slots: np.ndarray
                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """One bucketed batched-fit launch over ``slots`` (client rows),
-        padded to a power of two with zero-weight slots."""
+        padded to a power of two with zero-weight slots.  A wave that is
+        the resident chunk stages only its labels and weights."""
         from repro.models.weak import fit_stump_batched
         Bw = len(slots)
         BP = max(8, _next_pow2(Bw))
         pad = BP - Bw
+        resident = self._reads_resident(slots, BP)
         with obs.span("train.fit_batch", n_slots=Bw, padded=BP):
             with obs.span("train.fleet.gather"):
-                ops = [self.X[slots], self.Y[slots],
-                       self.D[slots], self.THR[slots]]
+                ops = [self.Y[slots], self.D[slots]]
+                if not resident:
+                    ops = [self.X[slots]] + ops + [self.THR[slots]]
                 staged = sum(a.nbytes for a in ops)
                 if pad:
                     ops = [np.concatenate(
@@ -203,6 +226,9 @@ class FleetCore:
                     staged += sum(a.nbytes for a in ops)
                 obs.count("train.fleet.gather_bytes", staged)
             args = self._to_device(*ops)
+            if resident:
+                args = (self._Xd,) + args + (self._THRd,)
+                obs.count("train.fleet.resident_waves")
             with obs.span("train.fleet.fit"):
                 params = fit_stump_batched(*args,
                                            backend=self._fit_backend(args))
@@ -224,11 +250,11 @@ class FleetCore:
         distribution update, for every fitted slot at once."""
         with obs.span("train.fleet.local_update"):
             with obs.span("train.fleet.gather"):
-                xb, yb, Db = self.X[slots], self.Y[slots], self.D[slots]
+                # the fitted column of each slot: (Bw, N)
+                xsel = self.X[slots, :, f]
+                yb, Db = self.Y[slots], self.D[slots]
                 obs.count("train.fleet.gather_bytes",
-                          xb.nbytes + yb.nbytes + Db.nbytes)
-            xsel = np.take_along_axis(xb, f[:, None, None], axis=2)[:, :, 0]
-            del xb
+                          xsel.nbytes + yb.nbytes + Db.nbytes)
             h = pol[:, None] * np.sign(xsel - thr[:, None] + 1e-12)
             pred = np.where(h > 0, 1.0, -1.0).astype(_F32)
             eps = np.sum(Db * (pred != yb), axis=1, dtype=_F32)
@@ -403,17 +429,22 @@ class FleetCore:
 
     # ---------------------------------------------------------------- run
     def run(self) -> None:
-        """Run the job and finalize the engine's metrics."""
-        if self.m.mode == "baseline":
-            self._run_baseline()
-        else:
-            self._run_enhanced()
-        with obs.span("train.fleet.finish"):
-            # hand the accumulated margins back so the engine's _finalize
-            # / _val_error see the fleet-computed state
-            self.eng._val_margin, self.eng._test_margin = self._to_device(
-                self.Mval, self.Mtest)
-            self.eng._finalize()
+        """Run the job and finalize the engine's metrics.  The resident
+        rows and grids are released at the end, so they are gone before
+        the next job's grid launch."""
+        try:
+            if self.m.mode == "baseline":
+                self._run_baseline()
+            else:
+                self._run_enhanced()
+            with obs.span("train.fleet.finish"):
+                # hand the accumulated margins back so the engine's
+                # _finalize / _val_error see the fleet-computed state
+                self.eng._val_margin, self.eng._test_margin = (
+                    self._to_device(self.Mval, self.Mtest))
+                self.eng._finalize()
+        finally:
+            self._Xd = self._THRd = None
 
     def _run_baseline(self) -> None:
         """Synchronous baseline, fleet profile.  Same TRIGGER/BARRIER
