@@ -6,18 +6,40 @@ reference profile runs one device dispatch per client fit and one python
 merge per learner — fine at 32 clients, hopeless at 100 000.  The fleet
 profile keeps the *same event-queue semantics* but restructures the math:
 
-* **Stacked shards.**  Client shards are padded to the fleet's max rows and
-  stacked into one ``(B, N, F)`` array; padding rows carry zero distribution
-  mass, which every batched kernel treats as "contributes nothing".  The
-  per-client quantile threshold grids come from one
-  ``stump_thresholds_batched`` launch at construction.
+* **Stacked or packed shards, chosen from the fleet's shape.**  Client
+  shards are padded to the fleet's max rows and stacked into one
+  ``(B, N, F)`` array; padding rows carry zero distribution mass, which
+  every batched kernel treats as "contributes nothing".  The per-client
+  quantile threshold grids come from one ``stump_thresholds_batched``
+  launch at construction.  Where that stack would be mostly padding —
+  its *row fill*, real rows over ``B * N``, below ``PACKED_BELOW_FILL``
+  (1/4) — and the fleet fits one threshold chunk, the shards are packed
+  instead: the rows of every client back to back ``(R, F)``, with labels,
+  weights and each row's client ``(R,)`` and per-client offsets
+  ``(B+1,)``.  Their grids come from one ``stump_thresholds_segmented``
+  launch, in ``(B, T, F)``, and the fits from the ragged stump scan
+  (``fit_stump_ragged``), which keeps no per-client error grid.  The
+  crossover sits between the fleets the benchmark runs: LEAF FEMNIST
+  (2,048 writers, largest 573 rows) stacks at a fill of 39.6%, LEAF
+  Sent140 (16,384 users, mean 2.42 rows, largest 97) would stack at
+  2.49% — 47.7 GB at 7,500 features, where its packed rows are 1.19 GB —
+  and packs; an even split such as ``mobile_100k`` stacks at a fill near
+  100%.  Below a quarter, the stack launches four padding rows for every
+  real one, more than the ragged scan's per-row cost over the batched
+  scan's (a row at a time against a vectorized block) was taken to be;
+  where between 2.49% and 39.6% the two layouts really cross is left for
+  the two cells' numbers to decide.
 * **Resident wave staging.**  A fleet that fits one threshold chunk keeps
   the chunk's rows and grids where the grid launch put them, on the device.
   A fit wave of every client in order, padded to that chunk, reads them
   there and stages only its labels and weights from the host; every other
   wave (partial, permuted, a fleet of several chunks or of fewer than 8
   clients) gathers and pads its four operands on the host.  The local
-  update gathers only the fitted feature column of each slot.
+  update gathers only the fitted feature column of each slot.  A packed
+  fleet keeps its rows and grids on the device the same way: a wave of
+  every client in order reads them there, and other waves gather their
+  clients' rows on the host and their grids on the device.  No host copy
+  of a packed fleet's grid is made.
 * **Deferred, batched fits.**  A client leg between syncs is causally
   closed, so its *timing* walk (availability/compute/stall/link draws — the
   behavior calls, in the reference call order) runs eagerly while the stump
@@ -45,12 +67,15 @@ Only the ``stump`` weak learner is supported — the batched launch path is
 stump-specific (the other learners never run at fleet scale).
 
 Tracing is per phase, never per client: a job is one ``train.fleet.run``
-span holding the shard stacking, the threshold grids, the first timing
+span holding the shard stacking (``train.fleet.stack``, or
+``train.fleet.pack`` for packed shards), the threshold grids, the first
+timing
 walk, each fit wave (``train.fit_batch``: gather, transfer, fit) and its
 local update, one ``train.fleet.sync`` per arrival, and the finish; the
 ``train.fleet.*`` counters add the bytes handed to the device and copied
-on the host, the slots and rows the waves launch, and the waves that
-read the resident stack.  With ``obs.tracing(profiler=True)`` the spans
+on the host, the slots and rows the waves launch (for packed waves the
+rows, block padding included, and the clients), and the waves that read
+the resident stack.  With ``obs.tracing(profiler=True)`` the spans
 land in a ``jax.profiler`` trace beside the device ops, so the device's
 idle time can be split by phase.
 """
@@ -68,6 +93,12 @@ from repro.core.compensation import staleness_scale
 # threshold-grid launches are chunked to this many clients (padded to the
 # chunk size, so the jit cache holds exactly one entry per fleet dtype)
 THRESHOLD_CHUNK = 16384
+# a fleet whose padded stack would hold fewer real rows than this share
+# is packed (module docstring)
+PACKED_BELOW_FILL = 0.25
+# packed rows are padded to a multiple of this: every row block of the
+# ragged scan's layouts divides it
+ROW_ALIGN = 512
 # server-side re-weighting materializes at most (n_val x SERVER_CHUNK)
 SERVER_CHUNK = 4096
 _F32 = np.float32
@@ -94,29 +125,21 @@ class FleetCore:
         self.clients = eng.clients
         B = len(self.clients)
 
-        # ---- stacked, padded shards (pad rows: x=0, y=0, D=0) ----
-        with obs.span("train.fleet.stack"):
-            self.n_valid = np.array([c.x.shape[0] for c in self.clients],
-                                    np.int32)
-            N = int(self.n_valid.max())
-            F = int(np.asarray(self.clients[0].x).shape[1])
-            self.X = np.zeros((B, N, F), _F32)
-            self.Y = np.zeros((B, N), _F32)
-            self.D = np.zeros((B, N), _F32)
-            for b, c in enumerate(self.clients):
-                n = int(self.n_valid[b])
-                self.X[b, :n] = np.asarray(c.x, _F32)
-                self.Y[b, :n] = np.asarray(c.y, _F32)
-                yb = self.Y[b, :n]
-                if self.cfg.balanced_init:
-                    pos = (yb > 0).astype(_F32)
-                    npos = max(float(pos.sum()), 1.0)
-                    nneg = max(n - float(pos.sum()), 1.0)
-                    self.D[b, :n] = pos / (2 * npos) + (1 - pos) / (2 * nneg)
-                else:
-                    self.D[b, :n] = 1.0 / n
-        with obs.span("train.fleet.thresholds"):
-            self.THR = self._build_thresholds()                # (B, F, T)
+        self.n_valid = np.array([c.x.shape[0] for c in self.clients],
+                                np.int32)
+        N = int(self.n_valid.max())
+        self.packed = (B <= THRESHOLD_CHUNK and int(self.n_valid.sum())
+                       < PACKED_BELOW_FILL * B * N)
+        self._Xd = self._THRd = self._segd = None
+        if self.packed:
+            with obs.span("train.fleet.pack"):
+                self._pack()
+            with obs.span("train.fleet.thresholds"):
+                self._build_thresholds_packed()
+        else:
+            self._stack(N)
+            with obs.span("train.fleet.thresholds"):
+                self.THR = self._build_thresholds()            # (B, F, T)
 
         # ---- server state mirrors (numpy-side ensemble view) ----
         xv, yv = eng.data["val"]
@@ -139,6 +162,56 @@ class FleetCore:
         # the (possibly still unresolved) params
         self._entry_bytes = (int(eng.weak.param_bytes(None))
                              + ENTRY_OVERHEAD_BYTES)
+
+    def _stack(self, N: int) -> None:
+        """Stacked, padded shards (pad rows: x=0, y=0, D=0)."""
+        B = len(self.clients)
+        with obs.span("train.fleet.stack"):
+            F = int(np.asarray(self.clients[0].x).shape[1])
+            self.X = np.zeros((B, N, F), _F32)
+            self.Y = np.zeros((B, N), _F32)
+            self.D = np.zeros((B, N), _F32)
+            for b, c in enumerate(self.clients):
+                n = int(self.n_valid[b])
+                self.X[b, :n] = np.asarray(c.x, _F32)
+                self.Y[b, :n] = np.asarray(c.y, _F32)
+                yb = self.Y[b, :n]
+                if self.cfg.balanced_init:
+                    pos = (yb > 0).astype(_F32)
+                    npos = max(float(pos.sum()), 1.0)
+                    nneg = max(n - float(pos.sum()), 1.0)
+                    self.D[b, :n] = pos / (2 * npos) + (1 - pos) / (2 * nneg)
+                else:
+                    self.D[b, :n] = 1.0 / n
+
+    def _pack(self) -> None:
+        """Packed shards: every client's rows back to back, padded to
+        ``ROW_ALIGN`` rows with rows of no client (seg = B, x=0, y=0,
+        D=0); ``off[b]:off[b+1]`` are client b's rows."""
+        B = len(self.clients)
+        n = self.n_valid.astype(np.int64)
+        self.off = np.concatenate([[0], np.cumsum(n)])
+        R = int(self.off[-1])
+        Rp = -(-R // ROW_ALIGN) * ROW_ALIGN
+        F = int(np.asarray(self.clients[0].x).shape[1])
+        self.X = np.zeros((Rp, F), _F32)
+        np.concatenate([np.asarray(c.x, _F32) for c in self.clients],
+                       out=self.X[:R])
+        self.Y = np.zeros(Rp, _F32)
+        np.concatenate([np.asarray(c.y, _F32) for c in self.clients],
+                       out=self.Y[:R])
+        self.seg = np.full(Rp, B, np.int32)
+        self.seg[:R] = np.repeat(np.arange(B, dtype=np.int32), n)
+        self.D = np.zeros(Rp, _F32)
+        s = self.seg[:R]
+        if self.cfg.balanced_init:
+            pos = (self.Y[:R] > 0).astype(_F32)
+            npos = np.add.reduceat(pos, self.off[:-1]).astype(np.float64)
+            nneg = np.maximum(n - npos, 1.0).astype(_F32)
+            npos = np.maximum(npos, 1.0).astype(_F32)
+            self.D[:R] = pos / (2 * npos[s]) + (1 - pos) / (2 * nneg[s])
+        else:
+            self.D[:R] = (1.0 / n)[s]
 
     # ------------------------------------------------------------ batched fits
     @staticmethod
@@ -179,7 +252,20 @@ class FleetCore:
             self._Xd, self._THRd = args[0], g
         return np.concatenate(grids)[:B]
 
-    def _fit_backend(self, xb) -> Optional[str]:
+    def _build_thresholds_packed(self) -> None:
+        """The packed fleet's grids, in one launch, kept on the device
+        with its rows and row clients (``_Xd``, ``_THRd``, ``_segd``);
+        nothing of the grid comes back to the host."""
+        from repro.models.weak import stump_thresholds_segmented
+        self.THR = None
+        args = self._to_device(self.X, self.seg, self.n_valid)
+        with obs.span("train.fleet.grid_wait"):
+            g = stump_thresholds_segmented(*args)
+            g.block_until_ready()
+        self._Xd, self._segd, self._THRd = args[0], args[1], g
+
+    def _fit_backend(self, xb, kernel: str = "stump_scan_batched"
+                     ) -> Optional[str]:
         """Resolve the batched-fit backend.  No policy keeps the jnp
         oracle (a single vmapped XLA launch — the right default off-TPU);
         a policy resolves normally except that the *interpret* substrate is
@@ -190,24 +276,26 @@ class FleetCore:
         if policy is None:
             return None
         from repro.kernels import dispatch as kdispatch
-        bucket = kdispatch.bucket_of("stump_scan_batched", xb)
-        name = policy.resolve("stump_scan_batched", bucket).name
+        bucket = kdispatch.bucket_of(kernel, xb)
+        name = policy.resolve(kernel, bucket).name
         if name == "interpret" and xb[0].shape[0] >= 64:
             name = "xla"
-            policy.choices[("stump_scan_batched", bucket)] = name
+            policy.choices[(kernel, bucket)] = name
         return name
 
     def _reads_resident(self, slots: np.ndarray, BP: int) -> bool:
         """Whether a wave over ``slots`` padded to ``BP`` is exactly the
         resident chunk: every client, in order, with the chunk's pad."""
         return (self._Xd is not None and self._Xd.shape[0] == BP
-                and np.array_equal(slots, np.arange(self.X.shape[0])))
+                and np.array_equal(slots, np.arange(len(self.clients))))
 
     def _fit_wave(self, slots: np.ndarray
                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """One bucketed batched-fit launch over ``slots`` (client rows),
         padded to a power of two with zero-weight slots.  A wave that is
         the resident chunk stages only its labels and weights."""
+        if self.packed:
+            return self._fit_wave_packed(slots)
         from repro.models.weak import fit_stump_batched
         Bw = len(slots)
         BP = max(8, _next_pow2(Bw))
@@ -242,12 +330,82 @@ class FleetCore:
         obs.count("train.fleet.real_rows", int(self.n_valid[slots].sum()))
         return f, thr, pol
 
+    def _wave_rows(self, slots: np.ndarray):
+        """The packed rows of ``slots``, client by client, each row's
+        index in the wave's client list, and where each client starts in
+        that order."""
+        lens = self.n_valid[slots].astype(np.int64)
+        starts = np.concatenate([[0], np.cumsum(lens)[:-1]])
+        local = np.repeat(np.arange(len(slots)), lens)
+        rows = self.off[slots][local] + (np.arange(int(lens.sum()))
+                                         - starts[local])
+        return rows, local, starts
+
+    def _fit_wave_packed(self, slots: np.ndarray
+                         ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """One ragged-fit launch over the packed rows of ``slots``.  A
+        wave of every client in order reads the rows, row clients and
+        grids where the grid launch left them and stages its labels and
+        weights; any other wave gathers its clients' rows on the host
+        (padded to a power of two, at least ``ROW_ALIGN``, with rows of
+        no client) and their grids on the device (clients padded to a
+        power of two)."""
+        import jax.numpy as jnp
+        from repro.models.weak import fit_stump_ragged
+        B, Bw = len(self.clients), len(slots)
+        resident = np.array_equal(slots, np.arange(B))
+        BP = Bw if resident else max(8, _next_pow2(Bw))
+        with obs.span("train.fit_batch", n_slots=Bw, padded=BP):
+            with obs.span("train.fleet.gather"):
+                if resident:
+                    ops = [self.Y, self.D]
+                    real = int(self.off[-1])
+                else:
+                    rows, local, _ = self._wave_rows(slots)
+                    real = len(rows)
+                    Rp = max(ROW_ALIGN, _next_pow2(real))
+                    ops = [np.zeros((Rp,) + a.shape[1:], a.dtype)
+                           for a in (self.X, self.Y, self.D)]
+                    for o, a in zip(ops, (self.X, self.Y, self.D)):
+                        o[:real] = a[rows]
+                    seg = np.full(Rp, BP, np.int32)
+                    seg[:real] = local
+                    ops.append(seg)
+                    obs.count("train.fleet.gather_bytes",
+                              sum(a.nbytes for a in ops))
+            args = self._to_device(*ops)
+            if resident:
+                x, seg, thr = self._Xd, self._segd, self._THRd
+                y, w = args
+                obs.count("train.fleet.resident_waves")
+            else:
+                x, y, w, seg = args
+                idx = np.zeros(BP, np.int32)
+                idx[:Bw] = slots
+                thr = jnp.take(self._THRd, jnp.asarray(idx), axis=0)
+            with obs.span("train.fleet.fit"):
+                params = fit_stump_ragged(
+                    x, y, w, seg, thr,
+                    backend=self._fit_backend((x, y, w, seg, thr),
+                                              "stump_scan_ragged"))
+                f = np.asarray(params["feature"])[:Bw].astype(np.int64)
+                thr = np.asarray(params["threshold"], _F32)[:Bw]
+                pol = np.asarray(params["polarity"], _F32)[:Bw]
+        obs.count("train.fit_batches")
+        obs.count("train.fits", Bw)
+        obs.count("train.fleet.packed_rows_launched", x.shape[0])
+        obs.count("train.fleet.segments_launched", Bw)
+        obs.count("train.fleet.real_rows", real)
+        return f, thr, pol
+
     def _local_update(self, slots: np.ndarray, f: np.ndarray,
                       thr: np.ndarray, pol: np.ndarray
                       ) -> Tuple[np.ndarray, np.ndarray]:
         """Vectorized mirror of the reference ``_train_one`` tail: eps on
         the pre-update distribution, the local alpha, and the eq.-(4)
         distribution update, for every fitted slot at once."""
+        if self.packed:
+            return self._local_update_packed(slots, f, thr, pol)
         with obs.span("train.fleet.local_update"):
             with obs.span("train.fleet.gather"):
                 # the fitted column of each slot: (Bw, N)
@@ -263,6 +421,28 @@ class FleetCore:
             w = Db * np.exp(-alpha[:, None] * yb * h)
             Z = np.sum(w, axis=1, dtype=_F32)
             self.D[slots] = w / (Z[:, None] + 1e-30)
+        return eps, alpha
+
+    def _local_update_packed(self, slots: np.ndarray, f: np.ndarray,
+                             thr: np.ndarray, pol: np.ndarray
+                             ) -> Tuple[np.ndarray, np.ndarray]:
+        """:meth:`_local_update` over packed rows: each row reads the
+        feature its client fitted, and the sums run per client segment."""
+        with obs.span("train.fleet.local_update"):
+            with obs.span("train.fleet.gather"):
+                rows, local, starts = self._wave_rows(slots)
+                xsel = self.X[rows, f[local]]
+                yb, Db = self.Y[rows], self.D[rows]
+                obs.count("train.fleet.gather_bytes",
+                          xsel.nbytes + yb.nbytes + Db.nbytes)
+            h = pol[local] * np.sign(xsel - thr[local] + 1e-12)
+            pred = np.where(h > 0, 1.0, -1.0).astype(_F32)
+            eps = np.add.reduceat(Db * (pred != yb), starts, dtype=_F32)
+            epsc = np.clip(eps, 1e-6, 1.0 - 1e-6)
+            alpha = (0.5 * np.log((1.0 - epsc) / epsc)).astype(_F32)
+            w = Db * np.exp(-alpha[local] * yb * h)
+            Z = np.add.reduceat(w, starts, dtype=_F32)
+            self.D[rows] = w / (Z[local] + 1e-30)
         return eps, alpha
 
     def _defer_fit(self, c) -> BufferEntry:
@@ -367,7 +547,7 @@ class FleetCore:
         K = w1 - w0
         if K <= 0:
             return
-        B = self.X.shape[0]
+        B = len(self.clients)
         cap = self.cfg.catch_up_cap
         owners = np.asarray(self.eng._owners[w0:w1], np.int64)
         if cap is None:
@@ -387,15 +567,32 @@ class FleetCore:
         ct = np.asarray(self._lt[cand.start:cand.stop], _F32)
         cp = np.asarray(self._lp[cand.start:cand.stop], _F32)
         ca = np.asarray(self._la[cand.start:cand.stop], _F32)
-        Macc = np.zeros_like(self.D)
-        for w in range(W):
-            h = cp[w] * np.sign(self.X[:, :, cf[w]] - ct[w] + 1e-12)
-            Macc += (ca[w] * sel[:, w].astype(_F32))[:, None] * h
-        wgt = self.D * np.exp(-self.Y * Macc)
-        Z = np.sum(wgt, axis=1, dtype=_F32)
-        self.D = wgt / (Z[:, None] + 1e-30)
+        if self.packed:
+            self._catch_up_packed(sel, cf, ct, cp, ca)
+        else:
+            Macc = np.zeros_like(self.D)
+            for w in range(W):
+                h = cp[w] * np.sign(self.X[:, :, cf[w]] - ct[w] + 1e-12)
+                Macc += (ca[w] * sel[:, w].astype(_F32))[:, None] * h
+            wgt = self.D * np.exp(-self.Y * Macc)
+            Z = np.sum(wgt, axis=1, dtype=_F32)
+            self.D = wgt / (Z[:, None] + 1e-30)
         for c in self.clients:
             c.last_merged_idx = w1
+
+    def _catch_up_packed(self, sel, cf, ct, cp, ca) -> None:
+        """The fleet-wide fold of :meth:`_catch_up_fleet` over packed
+        rows: each row takes its client's mask, the sums run per client
+        segment."""
+        R = int(self.off[-1])
+        s = self.seg[:R]
+        Macc = np.zeros(R, _F32)
+        for w in range(len(cf)):
+            h = cp[w] * np.sign(self.X[:R, cf[w]] - ct[w] + 1e-12)
+            Macc += (ca[w] * sel[:, w].astype(_F32))[s] * h
+        wgt = self.D[:R] * np.exp(-self.Y[:R] * Macc)
+        Z = np.add.reduceat(wgt, self.off[:-1], dtype=_F32)
+        self.D[:R] = wgt / (Z[s] + 1e-30)
 
     def _catch_up_client(self, c) -> None:
         """Per-client capped catch-up at its own sync (enhanced mode):
@@ -422,10 +619,13 @@ class FleetCore:
         thr = np.array([self._lt[i] for i in idxs], _F32)
         pol = np.array([self._lp[i] for i in idxs], _F32)
         a = np.array([self._la[i] for i in idxs], _F32)
-        h = pol[None, :] * np.sign(self.X[b][:, f] - thr[None, :] + 1e-12)
-        wgt = self.D[b] * np.exp(-self.Y[b] * (h @ a))
+        rows = (slice(self.off[b], self.off[b + 1]) if self.packed
+                else b)
+        h = pol[None, :] * np.sign(self.X[rows][:, f] - thr[None, :]
+                                   + 1e-12)
+        wgt = self.D[rows] * np.exp(-self.Y[rows] * (h @ a))
         Z = float(np.sum(wgt, dtype=_F32))
-        self.D[b] = wgt / (Z + 1e-30)
+        self.D[rows] = wgt / (Z + 1e-30)
 
     # ---------------------------------------------------------------- run
     def run(self) -> None:
@@ -444,7 +644,7 @@ class FleetCore:
                     self._to_device(self.Mval, self.Mtest))
                 self.eng._finalize()
         finally:
-            self._Xd = self._THRd = None
+            self._Xd = self._THRd = self._segd = None
 
     def _run_baseline(self) -> None:
         """Synchronous baseline, fleet profile.  Same TRIGGER/BARRIER
@@ -454,7 +654,7 @@ class FleetCore:
         per message at 100k clients buys nothing."""
         cfg, m, eng = self.cfg, self.m, self.eng
         vc = events.VirtualClock()
-        B = self.X.shape[0]
+        B = len(self.clients)
         all_slots = np.arange(B)
         pending_late: List[Tuple[int, BufferEntry]] = []
         t = 0.0

@@ -38,8 +38,9 @@ table entry.
 Calibration is a **layout autotune**, not just a backend choice:
 ``KernelPolicy.calibrate_call`` times each available backend over a small
 grid of block layouts (``LAYOUT_GRIDS`` — ``(block_t, block_n)`` for the
-vote kernels, ``block_n`` for stump_scan/dist_update, ``(block_q,
-block_k)`` for flash attention, following the xformers Triton config-sweep
+vote kernels, ``block_n`` for stump_scan/dist_update, ``(block_n,
+block_f)`` for the ragged stump scan, ``(block_q, block_k)`` for flash
+attention, following the xformers Triton config-sweep
 pattern) and records the ``(winner_backend, winner_layout)`` pair per
 (kernel, bucket).  ``dispatch()`` then injects the winning layout kwargs
 on every resolved call whose backend matches the winner — explicit caller
@@ -70,6 +71,7 @@ from repro.kernels.ensemble_vote import (
     ensemble_vote_batched_kernel, stump_vote_batched_kernel,
     stump_vote_fp_batched_kernel)
 from repro.kernels.flash_attention import flash_attention_kernel
+from repro.kernels.stump_ragged import SMEM_ROWS, ragged_scan_kernel
 from repro.kernels.stump_scan import stump_scan_kernel
 
 ENV_VAR = "REPRO_KERNEL_BACKEND"
@@ -85,7 +87,7 @@ LayoutKey = Tuple[Tuple[str, int], ...]  # canonical (sorted items) form
 # The block-shape kwargs the autotuner owns.  Any of these passed as None
 # by an ops wrapper means "let the calibration table (or the reference
 # layout) decide"; an explicit int always wins.
-LAYOUT_KWARGS = ("block_t", "block_n", "block_q", "block_k")
+LAYOUT_KWARGS = ("block_t", "block_n", "block_q", "block_k", "block_f")
 
 # Reference layouts: the pre-autotune hardcoded defaults.  Buckets are
 # always computed against these (layout-canonical bucketing), and they are
@@ -94,6 +96,7 @@ LAYOUT_KWARGS = ("block_t", "block_n", "block_q", "block_k")
 DEFAULT_LAYOUTS: Dict[str, Layout] = {
     "stump_scan": {"block_n": 256},
     "stump_scan_batched": {"block_n": 256},
+    "stump_scan_ragged": {"block_n": 128, "block_f": 128},
     "ensemble_vote": {"block_t": 128, "block_n": 512},
     "ensemble_vote_batched": {"block_t": 128, "block_n": 512},
     "stump_vote_batched": {"block_t": 128, "block_n": 512},
@@ -118,6 +121,10 @@ LAYOUT_GRIDS: Dict[str, List[Layout]] = {
                    {"block_n": 1024}],
     "stump_scan_batched": [{"block_n": 128}, {"block_n": 256},
                            {"block_n": 512}, {"block_n": 1024}],
+    "stump_scan_ragged": [{"block_n": 64, "block_f": 128},
+                          {"block_n": 128, "block_f": 128},   # reference
+                          {"block_n": 256, "block_f": 128},
+                          {"block_n": 128, "block_f": 256}],
     "ensemble_vote": _VOTE_GRID,
     "ensemble_vote_batched": _VOTE_GRID,
     "stump_vote_batched": _VOTE_GRID,
@@ -243,6 +250,45 @@ def _pallas_stump_scan_batched(x, y, w, thresholds, *, block_n=256,
     return jnp.swapaxes(err, 1, 2)[:B]
 
 
+def ragged_tables(seg, n_clients: int, block_n: int, block_c: int):
+    """The ragged scan's per-row-block tables, from each row's client
+    (non-decreasing; rows past the last client are padding): the client
+    block of each row block's first row, and the range of clients whose
+    last row lies in each row block (a client without rows takes the
+    last row before it, so folds once, after the clients below it)."""
+    nrb = seg.shape[0] // block_n
+    kk = seg[::block_n] >> (block_c.bit_length() - 1)
+    seg = seg[:nrb * block_n]
+    last = jnp.searchsorted(seg, jnp.arange(n_clients, dtype=seg.dtype),
+                            side="right") - 1
+    blocks = jnp.arange(nrb, dtype=jnp.int32)
+    fold = (last // block_n).astype(jnp.int32)
+    lo = jnp.searchsorted(fold, blocks, side="left").astype(jnp.int32)
+    hi = jnp.searchsorted(fold, blocks, side="right").astype(jnp.int32)
+    return kk.astype(jnp.int32), lo, hi
+
+
+def _pallas_stump_scan_ragged(x, y, w, seg, thresholds, *, block_n=128,
+                              block_f=128, interpret=True):
+    # each row's weight on the side it misses: a when x > thr (the +1
+    # side; a miss unless y = +1), b otherwise; padding rows, appended
+    # to whole SMEM blocks (x itself is not copied), add 0 to the last
+    # client
+    B = thresholds.shape[0]
+    bc = next_pow2(max(block_n, 8))
+    sb = max(block_n, SMEM_ROWS)
+    s = pad_to(jnp.minimum(seg, B - 1).astype(jnp.int32), 0, sb,
+               value=B - 1)
+    a = pad_to(jnp.where(y == 1.0, 0.0, w).astype(jnp.float32), 0, sb)
+    b = pad_to(jnp.where(y == -1.0, 0.0, w).astype(jnp.float32), 0, sb)
+    kk, lo, hi = ragged_tables(s[:ceil_to(x.shape[0], block_n)], B,
+                               block_n, bc)
+    out = ragged_scan_kernel(x, s, a, b, thresholds, kk, lo, hi,
+                             block_n=block_n, block_f=block_f, block_c=bc,
+                             interpret=interpret)
+    return jnp.swapaxes(out, 0, 1).reshape(6, -1)[:, :B]
+
+
 def _pallas_stump_scan(x, y, w, thresholds, *, block_n=256, interpret=True):
     # the B = 1 case of the batched sweep
     return _pallas_stump_scan_batched(
@@ -336,6 +382,7 @@ def _pallas_dist_update(alpha, D, y, h, *, block_n=1024, interpret=True):
 _PALLAS_IMPLS: Dict[str, Callable] = {
     "stump_scan": _pallas_stump_scan,
     "stump_scan_batched": _pallas_stump_scan_batched,
+    "stump_scan_ragged": _pallas_stump_scan_ragged,
     "ensemble_vote": _pallas_ensemble_vote,
     "ensemble_vote_batched": _pallas_ensemble_vote_batched,
     "stump_vote_batched": _pallas_stump_vote_batched,
@@ -353,6 +400,7 @@ _PALLAS_IMPLS: Dict[str, Callable] = {
 
 _jit_stump_scan_ref = jax.jit(ref.stump_scan_ref)
 _jit_stump_scan_batched_ref = jax.jit(ref.stump_scan_batched_ref)
+_jit_stump_scan_ragged_ref = jax.jit(ref.stump_scan_ragged_ref)
 _jit_ensemble_vote_ref = jax.jit(ref.ensemble_vote_ref)
 _jit_ensemble_vote_batched_ref = jax.jit(ref.ensemble_vote_batched_ref)
 _jit_stump_vote_batched_ref = jax.jit(ref.stump_vote_batched_ref)
@@ -366,6 +414,9 @@ _XLA_IMPLS: Dict[str, Callable] = {
         lambda x, y, w, thr, **_: _jit_stump_scan_ref(x, y, w, thr),
     "stump_scan_batched":
         lambda x, y, w, thr, **_: _jit_stump_scan_batched_ref(x, y, w, thr),
+    "stump_scan_ragged":
+        lambda x, y, w, seg, thr, **_:
+            _jit_stump_scan_ragged_ref(x, y, w, seg, thr),
     "ensemble_vote":
         lambda m, a, **_: _jit_ensemble_vote_ref(m, a),
     "ensemble_vote_batched":
@@ -405,6 +456,12 @@ def _bucket_stump_scan_batched(x, y, w, thresholds, **_):
     return (next_pow2(B), ceil_to(N, bn), ceil_to(F, 8), ceil_to(T, 8))
 
 
+def _bucket_stump_scan_ragged(x, y, w, seg, thresholds, **_):
+    R, F = x.shape
+    B, T, _ = thresholds.shape
+    return (ceil_to(R, 128), ceil_to(B, 128), ceil_to(F, 128), T)
+
+
 def _bucket_ensemble_vote(margins, alphas, **_):
     T, N = margins.shape
     bt, bn = vote_blocks(T, N, 128, 512)
@@ -436,6 +493,7 @@ def _bucket_dist_update(alpha, D, y, h, **_):
 _BUCKETERS: Dict[str, Callable[..., Bucket]] = {
     "stump_scan": _bucket_stump_scan,
     "stump_scan_batched": _bucket_stump_scan_batched,
+    "stump_scan_ragged": _bucket_stump_scan_ragged,
     "ensemble_vote": _bucket_ensemble_vote,
     "ensemble_vote_batched": _bucket_vote_batched,
     "stump_vote_batched": _bucket_stump_vote_batched,

@@ -65,6 +65,28 @@ def stump_scan_batched(x: jnp.ndarray, y: jnp.ndarray, w: jnp.ndarray,
         policy=policy, backend=backend, interpret=interpret)
 
 
+def stump_scan_ragged(x: jnp.ndarray, y: jnp.ndarray, w: jnp.ndarray,
+                      seg: jnp.ndarray, thresholds: jnp.ndarray, *,
+                      block_n: Optional[int] = None,
+                      block_f: Optional[int] = None,
+                      backend: Optional[str] = None,
+                      policy: Optional[KernelPolicy] = None) -> jnp.ndarray:
+    """Each client's best stump of each polarity over packed rows.
+
+    x: (R,F) rows of every client back to back; y, w, seg: (R,), seg the
+    client of each row, non-decreasing, every client in [0, B) holding a
+    row before any client without one (rows at or past B are padding,
+    w = 0); thresholds: (B,T,Fp), Fp >= F (features past F are not
+    scanned) -> (6,B): least error, its flat index f*T + t and its
+    threshold for polarity +1, then for -1.  Pallas substrates walk F in
+    ``block_f`` tiles over ``block_n``-row blocks and keep no (B,T,F)
+    error grid."""
+    return dispatch.dispatch(
+        "stump_scan_ragged", (x, y, w, seg, thresholds),
+        dict(block_n=block_n, block_f=block_f),
+        policy=policy, backend=backend)
+
+
 def ensemble_vote(margins: jnp.ndarray, alphas: jnp.ndarray, *,
                   block_t: Optional[int] = None,
                   block_n: Optional[int] = None,

@@ -43,6 +43,37 @@ def stump_scan_batched_ref(x: jnp.ndarray, y: jnp.ndarray, w: jnp.ndarray,
     return jax.vmap(stump_scan_ref)(x, y, w, thresholds)
 
 
+def stump_scan_ragged_ref(x: jnp.ndarray, y: jnp.ndarray, w: jnp.ndarray,
+                          seg: jnp.ndarray, thresholds: jnp.ndarray
+                          ) -> jnp.ndarray:
+    """Each client's best stump of each polarity over packed rows.
+
+    x: (R,F) rows of all clients back to back; y, w: (R,); seg: (R,)
+    the client of each row, non-decreasing (rows at or past B are
+    padding and carry w = 0); thresholds: (B,T,Fp), Fp >= F, features
+    past F not scanned -> (6,B) f32: the least
+    error of polarity +1, its flat index f*T + t (lowest on ties) and its
+    threshold, then the same for polarity -1, whose error is 1 - err.
+    err[b] is :func:`stump_scan_ref` over client b's rows, as (F,T).
+    """
+    F = x.shape[1]
+    B, T, _ = thresholds.shape
+    thresholds = thresholds[:, :, :F]
+    s = jnp.minimum(seg, B - 1)
+    pred = jnp.where(x[:, None, :] > thresholds[s], 1.0, -1.0)   # (R,T,F)
+    miss = jnp.where(pred != y[:, None, None], w[:, None, None], 0.0)
+    err = jax.ops.segment_sum(miss.astype(jnp.float32), s, B,
+                              indices_are_sorted=True)             # (B,T,F)
+    err = jnp.swapaxes(err, 1, 2).reshape(B, F * T)
+    thr = jnp.swapaxes(thresholds, 1, 2).reshape(B, F * T)
+    out = []
+    for e in (err, 1.0 - err):
+        idx = jnp.argmin(e, axis=1)
+        out += [jnp.min(e, axis=1), idx.astype(jnp.float32),
+                jnp.take_along_axis(thr, idx[:, None], axis=1)[:, 0]]
+    return jnp.stack(out)
+
+
 def ensemble_vote_ref(margins: jnp.ndarray, alphas: jnp.ndarray) -> jnp.ndarray:
     """Weighted ensemble margin: H(x) = sum_t alpha_t h_t(x).
 

@@ -26,6 +26,9 @@ import jax.numpy as jnp
 
 Array = jnp.ndarray
 
+# clients whose thresholds one gather of the segmented grid makes
+GRID_CLIENTS = 1024
+
 
 # ---------------------------------------------------------------------------
 # decision stump
@@ -126,6 +129,91 @@ def stump_thresholds_batched(x: Array, n_valid: Array,
     take = lambda idx: jnp.take_along_axis(xs, idx[:, :, None], axis=1)
     grid = take(lo) * (1.0 - frac) + take(hi) * frac             # (B,T,F)
     return jnp.transpose(grid, (0, 2, 1))                        # (B,F,T)
+
+
+@functools.partial(jax.jit, static_argnames=("n_thresholds",))
+def stump_thresholds_segmented(x: Array, seg: Array, n_valid: Array,
+                               n_thresholds: int = 16) -> Array:
+    """Per-client quantile threshold grids over packed rows.
+
+    x: (R,F) the rows of every client back to back; seg: (R,) the client
+    of each row, non-decreasing, rows at or past B padding; n_valid: (B,)
+    rows per client -> (B,T,Fp), the layout the ragged stump scan reads:
+    Fp is F rounded up to whole 128-lane tiles, features past F hold 0
+    and are never scanned.  With whole lanes the device's default layout
+    of the grid is row-major, as the scan's kernel reads it; at F = 7,500
+    it would be another, and the kernel's operand would be copied first.
+    Matches ``stump_thresholds`` on each client's rows exactly: one sort
+    by (client, value) per feature puts each client's rows in order at
+    its offset (padding last), and the quantile positions are scaled by
+    the client's own count.  The grid is gathered straight into (B,T,Fp);
+    no transposed copy of it is made.
+    """
+    R = x.shape[0]
+    x = jnp.pad(x, ((0, 0), (0, -x.shape[1] % 128)))  # zero features
+    F = x.shape[1]
+    B = n_valid.shape[0]
+    qs = jnp.linspace(0.0, 1.0, n_thresholds + 2)[1:-1]          # (T,)
+    keys = jnp.broadcast_to(seg.astype(jnp.int32)[:, None], (R, F))
+    _, xs = jax.lax.sort((keys, x), dimension=0, num_keys=2)
+    start = jnp.cumsum(n_valid) - n_valid                        # (B,)
+    pos = qs[None, :] * (n_valid[:, None].astype(jnp.float32) - 1.0)
+    lo = jnp.floor(pos).astype(jnp.int32)                        # (B,T)
+    hi = jnp.ceil(pos).astype(jnp.int32)
+    frac = (pos - lo.astype(jnp.float32))[:, :, None]            # (B,T,1)
+    # gathered a chunk of clients at a time into the grid, so no
+    # grid-sized gather is ever held beside it
+    C = min(B, GRID_CLIENTS)
+
+    def chunk(k, grid):
+        # the last chunk is shifted back to end at B; its overlap with
+        # the one before rewrites the same values
+        c = jnp.minimum(k * C, B - C)
+        sl = lambda a: jax.lax.dynamic_slice_in_dim(a, c, C)
+        s0 = sl(start)[:, None]
+        take = lambda idx: jnp.take(xs, (s0 + sl(idx)).reshape(-1), axis=0,
+                                    mode="clip").reshape(C, n_thresholds, F)
+        fr = sl(frac)
+        part = take(lo) * (1.0 - fr) + take(hi) * fr
+        return jax.lax.dynamic_update_slice_in_dim(grid, part, c, 0)
+
+    grid = jnp.zeros((B, n_thresholds, F), x.dtype)
+    return jax.lax.fori_loop(0, -(-B // C), chunk, grid)         # (B,T,Fp)
+
+
+def _pick_ragged(best: Array, n_thresholds: int) -> Dict[str, Array]:
+    """``_pick_stump`` from the ragged scan's per-client bests: best is
+    (6,B) — least error, flat index f*T + t and threshold of polarity +1,
+    then of -1.  Polarity +1 wins ties, as there."""
+    take_pos = best[0] <= best[3]
+    pick = lambda k: jnp.where(take_pos, best[k], best[k + 3])
+    flat = pick(1).astype(jnp.int32)
+    return {"feature": flat // n_thresholds, "threshold": pick(2),
+            "polarity": jnp.where(take_pos, 1.0, -1.0)}
+
+
+@functools.partial(jax.jit, static_argnames=("backend",))
+def fit_stump_ragged(x: Array, y: Array, w: Array, seg: Array,
+                     thresholds: Array, backend: str | None = None
+                     ) -> Dict[str, Array]:
+    """The packed sibling of :func:`fit_stump_batched`: one stump per
+    client from rows of every client back to back.
+
+    x: (R,F); y, w, seg: (R,), seg the client of each row (non-decreasing,
+    rows at or past B padding with w = 0); thresholds: (B,T,Fp), Fp >= F,
+    as :func:`stump_thresholds_segmented` emits it (features past F are
+    not scanned).  Returns the stumps ``fit_stump_batched`` returns for
+    the same clients, rows and grids, as (B,) arrays; no (B,F,T) error
+    grid is made on the kernel path.
+    """
+    if backend is None:
+        from repro.kernels import ref as kref
+        best = kref.stump_scan_ragged_ref(x, y, w, seg, thresholds)
+    else:
+        from repro.kernels import ops as kops
+        best = kops.stump_scan_ragged(x, y, w, seg, thresholds,
+                                      backend=backend)
+    return _pick_ragged(best, thresholds.shape[1])
 
 
 STUMP_BYTES = 3 * 4   # feature idx + threshold + polarity

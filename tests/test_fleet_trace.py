@@ -244,3 +244,60 @@ def test_a_fleet_off_the_power_of_two_reads_the_padded_chunk(monkeypatch):
     _assert_same_learning(resident, host)
     assert resident[2]["train.fleet.resident_waves"] == 2
     assert host[2].get("train.fleet.resident_waves", 0) == 0
+
+
+def _packed_engine(n_clients: int, mode: str = "enhanced"):
+    """A fleet of LEAF Sent140's shape: most devices hold one or two rows,
+    a few dozens, so the padded stack would be mostly padding and the
+    fleet packs."""
+    rng = np.random.default_rng(0)
+    n = np.minimum(1 + rng.negative_binomial(0.0971, 0.0640, n_clients), 40)
+    n[1] = 40
+    R = int(n.sum())
+    x = rng.normal(size=(R + 40, 8)).astype(np.float32)
+    y = np.where(rng.random(R + 40) < 0.5, 1.0, -1.0).astype(np.float32)
+    cut = np.cumsum(n)
+    data = {"clients": [(x[s:e], y[s:e])
+                        for s, e in zip(np.r_[0, cut[:-1]], cut)],
+            "val": (x[R:R + 20], y[R:R + 20]),
+            "test": (x[R + 20:], y[R + 20:])}
+    cfg = FedBoostConfig(n_clients=n_clients, n_rounds=2, seed=3,
+                         dropout_prob=0.2, catch_up_cap=4,
+                         scheduler=SchedulerConfig(i_init=2))
+    return FederatedBoostEngine(cfg, data, mode, fleet=True), data
+
+
+@pytest.mark.parametrize("mode", ["baseline", "enhanced"])
+def test_packed_fleet_spans_and_counters_add_up(mode):
+    """The packed layout emits train.fleet.pack in place of the stack, and
+    its counters add up: every wave here is every client in order, so it
+    reads the resident rows and grids; the rows it launches hold every
+    real row and the alignment padding, no more."""
+    eng, data = _packed_engine(64, mode)
+    with obs.tracing(profile_kernels=False) as tr:
+        eng.run()
+        spans = tr.finished()
+        counters = {name: c.value for name, _, c in
+                    obs.get_registry().counters()}
+    names = {d["name"] for d in spans}
+    assert "train.fleet.pack" in names and "train.fleet.stack" not in names
+    assert PHASES - {"train.fleet.stack"} <= names
+    assert check_trace(spans) == []
+    B = len(data["clients"])
+    rows = sum(len(y) for _, y in data["clients"])
+    Rp = -(-rows // fleet.ROW_ALIGN) * fleet.ROW_ALIGN
+    waves = counters["train.fit_batches"]
+    assert waves == 2 and counters["train.fleet.resident_waves"] == waves
+    assert counters["train.fleet.real_rows"] == waves * rows
+    assert counters["train.fleet.packed_rows_launched"] == waves * Rp
+    assert (counters["train.fleet.packed_rows_launched"]
+            >= counters["train.fleet.real_rows"])
+    assert counters["train.fleet.segments_launched"] == waves * B
+    assert counters["train.fits"] == waves * B
+    assert "train.fleet.slots_launched" not in counters
+    # the grid launch's rows (f32), row clients (i32) and counts (i32),
+    # each wave's labels and weights, the margins: no grid and no rows
+    # go up with a wave
+    margins = (len(data["val"][1]) + len(data["test"][1])) * 4
+    assert counters["train.fleet.h2d_bytes"] == (
+        Rp * 8 * 4 + Rp * 4 + B * 4 + waves * 2 * Rp * 4 + margins)

@@ -1,5 +1,6 @@
 """Compile-only checks for a described TPU v5e: the six Pallas kernels of
-the main path at the shapes ``chip_smoke.py`` launches, and the 4-client
+the main path at the shapes ``chip_smoke.py`` launches, the ragged stump
+scan at the shapes of the LEAF Sent140 fleet, and the 4-client
 ``fed_mesh`` step over a 2x2 mesh.  Nothing runs; the TPU compiler (Mosaic
 for the kernels) refuses here what it would refuse on the chip.
 
@@ -21,7 +22,11 @@ from repro.kernels import dispatch
 # (kernel, operand shapes) — the largest shape of each kernel that
 # chip_smoke.py launches: the mobile_100k fit wave (2^17 slots of 4 rows),
 # dist_update over that wave's rows, the event engine's biggest client
-# shard, and the serving batches of the five tenants
+# shard, and the serving batches of the five tenants; and the ragged scan
+# over the Sent140 cell's fleet: 16,384 clients, 39,645 rows padded to
+# 39,936, 7,500 features, 16 thresholds on whole 128-lane tiles (an int32
+# operand is marked so)
+I32 = jnp.int32
 KERNEL_CASES = [
     ("stump_scan", [(1280, 32), (1280,), (1280,), (32, 16)]),
     ("stump_scan_batched",
@@ -30,6 +35,8 @@ KERNEL_CASES = [
     ("ensemble_vote_batched", [(5, 256, 128), (5, 256)]),
     ("stump_vote_batched", [(4, 256, 64), (4, 256), (4, 256), (4, 256)]),
     ("stump_vote_fp_batched", [(1, 96, 64), (1, 96), (1, 96), (1, 96)]),
+    ("stump_scan_ragged", [(39936, 7500), (39936,), (39936,),
+                           ((39936,), I32), (16384, 16, 7552)]),
 ]
 
 
@@ -61,8 +68,10 @@ def no_compile_cache():
                          ids=[k for k, _ in KERNEL_CASES])
 def test_kernel_compiles_for_tpu(kernel, shapes, topo, no_compile_cache):
     one_chip = SingleDeviceSharding(topo.devices[0])
-    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
-            for s in shapes]
+    args = []
+    for s in shapes:
+        shape, dtype = s if s and s[-1] is I32 else (s, jnp.float32)
+        args.append(jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip))
     layout = dispatch.DEFAULT_LAYOUTS[kernel]
     fn = lambda *a: dispatch._PALLAS_IMPLS[kernel](*a, interpret=False,
                                                    **layout)
