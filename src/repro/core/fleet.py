@@ -48,10 +48,16 @@ profile keeps the *same event-queue semantics* but restructures the math:
   ``fit_stump_batched`` launch (batch padded to a power of two so the jit
   cache stays small) — and each wave's local eps/alpha/distribution updates
   run vectorized in numpy.
-* **Vectorized server math.**  Server-side re-weighting, margin folds, and
-  the capped catch-up replay are numpy matrix ops (chunked so a
-  100k-learner round never materializes more than ``SERVER_CHUNK`` columns
-  at once).
+* **Vectorized server math, tabulated per fit wave.**  What the server
+  needs of a stump depends only on the stump and the held-out rows, so
+  each fit wave computes it for all its stumps at once (in blocks of
+  ``SERVER_CHUNK`` stumps): each stump's votes on the validation and test
+  rows, kept as one byte per row until the stump merges, and its server
+  alpha before compensation.  An arrival only reads its entries' rows:
+  it compensates their alphas, folds them into the margins with the same
+  float32 product, and counts the validation error.  The merged learners
+  are numpy columns, so the capped catch-up picks its learners with array
+  operations and replays them as numpy matrix ops.
 
 Communication/time accounting is identical integer/float bookkeeping to the
 reference profile — byte counts, message counts, and simulated clocks match
@@ -74,13 +80,15 @@ walk, each fit wave (``train.fit_batch``: gather, transfer, fit) and its
 local update, one ``train.fleet.sync`` per arrival, and the finish; the
 ``train.fleet.*`` counters add the bytes handed to the device and copied
 on the host, the slots and rows the waves launch (for packed waves the
-rows, block padding included, and the clients), and the waves that read
-the resident stack.  With ``obs.tracing(profiler=True)`` the spans
-land in a ``jax.profiler`` trace beside the device ops, so the device's
-idle time can be split by phase.
+rows, block padding included, and the clients), the waves that read
+the resident stack, and the stumps whose held-out votes were tabulated
+(outside any phase span, in ``train.fleet.run``'s self time).  With
+``obs.tracing(profiler=True)`` the spans land in a ``jax.profiler`` trace
+beside the device ops, so the device's idle time can be split by phase.
 """
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -102,6 +110,10 @@ ROW_ALIGN = 512
 # server-side re-weighting materializes at most (n_val x SERVER_CHUNK)
 SERVER_CHUNK = 4096
 _F32 = np.float32
+# a stump's held-out vote code: its validation vote + 1 in bits 0-1 and its
+# test vote + 1 in bits 2-3 (votes are -1, 0 or +1); these decode a code
+_VAL_VOTE = (np.arange(16) % 4 - 1).astype(_F32)
+_TEST_VOTE = (np.arange(16) // 4 - 1).astype(_F32)
 
 
 def _next_pow2(n: int) -> int:
@@ -109,6 +121,54 @@ def _next_pow2(n: int) -> int:
     while p < n:
         p *= 2
     return p
+
+
+@dataclass
+class _FleetEntry(BufferEntry):
+    """A buffered entry with what the server needs of its stump, filled
+    in when its fit wave resolves: the stump's vote codes on the held-out
+    rows, dropped once it merges, and its server alpha before
+    compensation."""
+    votes: Optional[np.ndarray] = None
+    server_alpha: float = 0.0
+
+
+class _Learners:
+    """The merged learners' columns in merge order, the source of every
+    catch-up window: feature, threshold, polarity, compensated server
+    alpha and owner, grown by doubling."""
+
+    _DTYPES = (("f", np.int64), ("thr", _F32), ("pol", _F32),
+               ("alpha", _F32), ("owner", np.int64))
+
+    def __init__(self) -> None:
+        self.n = 0
+        self._cols = {k: np.empty(64, dt) for k, dt in self._DTYPES}
+
+    def extend(self, **cols) -> None:
+        k, cap = len(cols["owner"]), len(self._cols["owner"])
+        if self.n + k > cap:
+            self._cols = {name: np.resize(a, max(2 * cap, self.n + k))
+                          for name, a in self._cols.items()}
+        for name, v in cols.items():
+            self._cols[name][self.n:self.n + k] = v
+        self.n += k
+
+    def __getitem__(self, name: str) -> np.ndarray:
+        return self._cols[name][:self.n]
+
+
+def _newest_foreign(owners: np.ndarray, lo: int, cid: int,
+                    cap: Optional[int], own_max: int) -> np.ndarray:
+    """Indices in ``[lo, len(owners))`` of the learners ``cid`` does not
+    own, oldest first: all of them where ``cap`` is None, else the newest
+    ``cap``, as the reference's reverse scan picks them.  ``cid`` owns at
+    most ``own_max`` learners, so those ``cap`` lie in the last
+    ``cap + own_max``."""
+    hi = len(owners)
+    w0 = lo if cap is None else max(lo, hi - cap - own_max)
+    idx = w0 + np.flatnonzero(owners[w0:hi] != cid)
+    return idx if cap is None else idx[max(0, len(idx) - cap):]
 
 
 class FleetCore:
@@ -144,20 +204,21 @@ class FleetCore:
         # ---- server state mirrors (numpy-side ensemble view) ----
         xv, yv = eng.data["val"]
         xt, yt = eng.data["test"]
-        self.xv = np.asarray(xv, _F32)
+        # held-out features by feature: row f is feature f of every row
+        self._xv_f = np.ascontiguousarray(np.asarray(xv, _F32).T)
+        self._xt_f = np.ascontiguousarray(np.asarray(xt, _F32).T)
         self.yv = np.asarray(yv, _F32)
-        self.xt = np.asarray(xt, _F32)
         self.yt = np.asarray(yt, _F32)
-        self.Mval = np.zeros(self.xv.shape[0], _F32)
-        self.Mtest = np.zeros(self.xt.shape[0], _F32)
-        # merged-learner columns, merge order (the catch-up window source)
-        self._lf: List[int] = []       # feature
-        self._lt: List[float] = []     # threshold
-        self._lp: List[float] = []     # polarity
-        self._la: List[float] = []     # compensated server alpha
-        # deferred fits: cid -> FIFO of unresolved BufferEntry (insertion
+        self.Mval = np.zeros(len(self.yv), _F32)
+        self.Mtest = np.zeros(len(self.yt), _F32)
+        # a vote of +1 misses a validation row not labelled +1, a vote of
+        # -1 one not labelled -1: ((vote > 0) ^ _yv_pos) | _yv_other
+        self._yv_pos = self.yv == 1.0
+        self._yv_other = (self.yv != 1.0) & (self.yv != -1.0)
+        self._learners = _Learners()
+        # deferred fits: cid -> FIFO of unresolved entries (insertion
         # order over cids is the wave's batch order)
-        self._pending: Dict[int, List[BufferEntry]] = {}
+        self._pending: Dict[int, List[_FleetEntry]] = {}
         # stump wire size is params-independent, so accounting never needs
         # the (possibly still unresolved) params
         self._entry_bytes = (int(eng.weak.param_bytes(None))
@@ -445,10 +506,10 @@ class FleetCore:
             self.D[rows] = w / (Z[local] + 1e-30)
         return eps, alpha
 
-    def _defer_fit(self, c) -> BufferEntry:
+    def _defer_fit(self, c) -> _FleetEntry:
         """Queue one deferred stump fit for client ``c``'s current round;
         the placeholder entry is filled in by the next resolution wave."""
-        e = BufferEntry(None, 0.0, 0.0, c.local_round)
+        e = _FleetEntry(None, 0.0, 0.0, c.local_round)
         c.local_round += 1
         self._pending.setdefault(c.cid, []).append(e)
         return e
@@ -464,6 +525,7 @@ class FleetCore:
                                 len(self._pending))
             f, thr, pol = self._fit_wave(slots)
             eps, alpha = self._local_update(slots, f, thr, pol)
+            wave = []
             for j, cid in enumerate(slots.tolist()):
                 fifo = self._pending[cid]
                 e = fifo.pop(0)
@@ -472,26 +534,47 @@ class FleetCore:
                             "polarity": float(pol[j])}
                 e.eps = float(eps[j])
                 e.alpha = float(alpha[j])
+                wave.append(e)
                 if not fifo:
                     del self._pending[cid]
+            self._tabulate(wave, f, thr, pol)
         obs.get_registry().gauge("train.pending_fits").set(0)
 
     # --------------------------------------------------------- server math
-    def _merge_window(self, entries: List[BufferEntry], owners: List[int],
+    def _tabulate(self, wave: List[_FleetEntry], f: np.ndarray,
+                  thr: np.ndarray, pol: np.ndarray) -> None:
+        """Give each entry of a fit wave what the server needs of its
+        stump, computed for the whole wave at once: its row of held-out
+        vote codes (an array of its own, freed when the stump merges)
+        and its server alpha before compensation."""
+        Bw, nv, nt = len(f), len(self.yv), len(self.yt)
+        for lo in range(0, Bw, SERVER_CHUNK):
+            s = slice(lo, min(lo + SERVER_CHUNK, Bw))
+            t, p = thr[s, None], pol[s, None]
+            # (stumps, rows): the predictor's arithmetic, element by element
+            hv = p * np.sign(self._xv_f[f[s]] - t + 1e-12)
+            ht = p * np.sign(self._xt_f[f[s]] - t + 1e-12)
+            codes = np.zeros((len(hv), max(nv, nt)), np.uint8)
+            codes[:, :nv] = hv + 1
+            codes[:, :nt] += (4 * ht + 4).astype(np.uint8)
+            for e, row, a in zip(wave[s], codes,
+                                 self._server_alphas(hv).tolist()):
+                e.votes, e.server_alpha = row.copy(), a
+        obs.count("train.fleet.vote_rows", Bw)
+
+    def _merge_window(self, entries: List[_FleetEntry], owners: List[int],
                       sync_round: int, compensated: bool) -> None:
-        """Fold ``entries`` into the global ensemble: vectorized server
-        re-weighting + margin folds, then the bookkeeping the reference
-        ``_merge`` does per entry."""
+        """Fold ``entries`` into the global ensemble from their tabulated
+        votes and alphas (staleness-compensated where ``compensated``),
+        then the bookkeeping the reference ``_merge`` does per entry.  The
+        fold's operand is (n, K) float32 with the layout a fancy-index
+        gather of K stump columns has, so the sums run in the same order
+        as the reference's."""
         if not entries:
             return
         eng, K = self.eng, len(entries)
-        f = np.array([e.params["feature"] for e in entries], np.int64)
-        thr = np.array([e.params["threshold"] for e in entries], _F32)
-        pol = np.array([e.params["polarity"] for e in entries], _F32)
-        a = np.empty(K, _F32)
-        for lo in range(0, K, SERVER_CHUNK):
-            s = slice(lo, min(lo + SERVER_CHUNK, K))
-            a[s] = self._server_alphas(f[s], thr[s], pol[s])
+        nv, nt = len(self.yv), len(self.yt)
+        a = np.array([e.server_alpha for e in entries], _F32)
         if compensated:
             scale = np.array(
                 [staleness_scale(max(0, sync_round - e.round_stamp),
@@ -500,40 +583,41 @@ class FleetCore:
             a = a * scale
         for lo in range(0, K, SERVER_CHUNK):
             s = slice(lo, min(lo + SERVER_CHUNK, K))
-            hv = pol[s] * np.sign(self.xv[:, f[s]] - thr[s] + 1e-12)
-            ht = pol[s] * np.sign(self.xt[:, f[s]] - thr[s] + 1e-12)
-            self.Mval += hv @ a[s]
-            self.Mtest += ht @ a[s]
-        for e, owner, ai in zip(entries, owners, a.tolist()):
-            eng.ensemble.add(e.params, ai)
-            eng._owners.append(owner)
-            eng._round_stamps.append(e.round_stamp)
-            self._lf.append(e.params["feature"])
-            self._lt.append(e.params["threshold"])
-            self._lp.append(e.params["polarity"])
-            self._la.append(ai)
+            codes = np.array([e.votes for e in entries[s]])    # (k, n)
+            self.Mval += _VAL_VOTE.take(codes[:, :nv]).T @ a[s]
+            self.Mtest += _TEST_VOTE.take(codes[:, :nt]).T @ a[s]
+        params = [e.params for e in entries]
+        for e, p, ai in zip(entries, params, a.tolist()):
+            e.votes = None
+            eng.ensemble.add(p, ai)
+        eng._owners.extend(owners)
+        eng._round_stamps.extend(e.round_stamp for e in entries)
+        self._learners.extend(f=[p["feature"] for p in params],
+                              thr=[p["threshold"] for p in params],
+                              pol=[p["polarity"] for p in params],
+                              alpha=a, owner=owners)
         self.m.learners_merged += K
 
-    def _server_alphas(self, f: np.ndarray, thr: np.ndarray,
-                       pol: np.ndarray) -> np.ndarray:
-        """Vectorized ``_server_alpha``: validation-set re-weighting for a
-        window of stump columns at once."""
-        h = pol[None, :] * np.sign(self.xv[:, f] - thr[None, :] + 1e-12)
-        pred = np.where(h > 0, 1.0, -1.0).astype(_F32)
-        yv = self.yv[:, None]
-        miss = pred != yv
+    def _server_alphas(self, h: np.ndarray) -> np.ndarray:
+        """Vectorized ``_server_alpha``: validation-set re-weighting of a
+        block of stumps at once, from their votes ``h`` (stumps, validation
+        rows).  The misses are counted, so the error rates are exact
+        whatever the block."""
+        miss = ((h > 0) ^ self._yv_pos) | self._yv_other
         if self.cfg.balanced_init:
-            pos, neg = yv > 0, yv < 0
-            ep = np.sum(miss & pos, axis=0) / max(float(pos.sum()), 1.0)
-            en = np.sum(miss & neg, axis=0) / max(float(neg.sum()), 1.0)
+            pos, neg = self.yv > 0, self.yv < 0
+            ep = np.sum(miss & pos, axis=1) / max(float(pos.sum()), 1.0)
+            en = np.sum(miss & neg, axis=1) / max(float(neg.sum()), 1.0)
             eps = np.clip(0.5 * (ep + en), 0.02, 0.98)
         else:
-            eps = np.clip(miss.mean(axis=0), 0.02, 0.98)
+            eps = np.clip(miss.mean(axis=1), 0.02, 0.98)
         return (0.5 * np.log((1.0 - eps) / eps)).astype(_F32)
 
     def _val_err(self) -> float:
-        pred = np.where(self.Mval > 0, 1.0, -1.0)
-        return float(np.mean(pred != self.yv))
+        """The share of validation rows the ensemble's sign gets wrong:
+        the reference's ``mean(where(M > 0, 1, -1) != y)`` as one count."""
+        return np.count_nonzero(
+            ((self.Mval > 0) ^ self._yv_pos) | self._yv_other) / len(self.yv)
 
     # ----------------------------------------------------------- catch-up
     def _catch_up_fleet(self, w0: int, w1: int) -> None:
@@ -549,24 +633,21 @@ class FleetCore:
             return
         B = len(self.clients)
         cap = self.cfg.catch_up_cap
-        owners = np.asarray(self.eng._owners[w0:w1], np.int64)
+        L = self._learners
+        owners = L["owner"][w0:w1]
         if cap is None:
             W = K
         else:
             maxdup = int(np.bincount(owners - owners.min()).max()) if K else 0
             W = min(K, cap + maxdup)
         cand = slice(w1 - W, w1)              # oldest -> newest candidates
-        co = np.asarray(self.eng._owners[cand.start:cand.stop], np.int64)
-        foreign = co[None, :] != np.arange(B)[:, None]          # (B, W)
+        foreign = L["owner"][cand][None, :] != np.arange(B)[:, None]
         if cap is None:
             sel = foreign
         else:
             rev = foreign[:, ::-1]
             sel = (rev & (np.cumsum(rev, axis=1) <= cap))[:, ::-1]
-        cf = np.asarray(self._lf[cand.start:cand.stop], np.int64)
-        ct = np.asarray(self._lt[cand.start:cand.stop], _F32)
-        cp = np.asarray(self._lp[cand.start:cand.stop], _F32)
-        ca = np.asarray(self._la[cand.start:cand.stop], _F32)
+        cf, ct, cp, ca = (L[k][cand] for k in ("f", "thr", "pol", "alpha"))
         if self.packed:
             self._catch_up_packed(sel, cf, ct, cp, ca)
         else:
@@ -596,29 +677,17 @@ class FleetCore:
 
     def _catch_up_client(self, c) -> None:
         """Per-client capped catch-up at its own sync (enhanced mode):
-        the reference reverse scan over [last_merged_idx, hi) skipping the
-        client's own entries, folded into one exponential."""
-        lo, hi = c.last_merged_idx, len(self._lf)
-        cap = self.cfg.catch_up_cap
-        owners = self.eng._owners
-        if cap is None:
-            idxs = [i for i in range(lo, hi) if owners[i] != c.cid]
-        else:
-            idxs = []
-            i = hi - 1
-            while i >= lo and len(idxs) < cap:
-                if owners[i] != c.cid:
-                    idxs.append(i)
-                i -= 1
-            idxs.reverse()
-        c.last_merged_idx = hi
-        if not idxs:
+        the foreign learners of [last_merged_idx, hi) that the reference
+        reverse scan picks, folded into one exponential.  A client owns
+        one learner per round at most."""
+        L = self._learners
+        idxs = _newest_foreign(L["owner"], c.last_merged_idx, c.cid,
+                              self.cfg.catch_up_cap, self.cfg.n_rounds)
+        c.last_merged_idx = L.n
+        if not len(idxs):
             return
         b = c.cid
-        f = np.array([self._lf[i] for i in idxs], np.int64)
-        thr = np.array([self._lt[i] for i in idxs], _F32)
-        pol = np.array([self._lp[i] for i in idxs], _F32)
-        a = np.array([self._la[i] for i in idxs], _F32)
+        f, thr, pol, a = (L[k][idxs] for k in ("f", "thr", "pol", "alpha"))
         rows = (slice(self.off[b], self.off[b + 1]) if self.packed
                 else b)
         h = pol[None, :] * np.sign(self.X[rows][:, f] - thr[None, :]
@@ -656,7 +725,7 @@ class FleetCore:
         vc = events.VirtualClock()
         B = len(self.clients)
         all_slots = np.arange(B)
-        pending_late: List[Tuple[int, BufferEntry]] = []
+        pending_late: List[Tuple[int, _FleetEntry]] = []
         t = 0.0
         vc.push(0.0, events.TRIGGER, payload=0)
         while vc:
@@ -666,17 +735,19 @@ class FleetCore:
                 f, thr, pol = self._fit_wave(all_slots)
                 eps, alpha = self._local_update(all_slots, f, thr, pol)
                 late, pending_late = pending_late, []
-                on_time: List[Tuple[int, BufferEntry]] = []
+                wave: List[_FleetEntry] = []
+                on_time: List[Tuple[int, _FleetEntry]] = []
                 durations: List[float] = []
                 with obs.span("train.fleet.walk"):
                     for b, c in enumerate(self.clients):
                         dropped = not c.behavior.availability(t0)
                         dur = c.behavior.compute_time(eng.BASE_ROUND_S, t0)
-                        e = BufferEntry(
+                        e = _FleetEntry(
                             {"feature": int(f[b]),
                              "threshold": float(thr[b]),
                              "polarity": float(pol[b])},
                             float(eps[b]), float(alpha[b]), c.local_round)
+                        wave.append(e)
                         c.local_round += 1
                         if dropped:
                             m.rounds_unavailable += 1
@@ -688,6 +759,7 @@ class FleetCore:
                         durations.append(
                             dur + c.behavior.link(t0).tx_time(up))
                         on_time.append((b, e))
+                self._tabulate(wave, f, thr, pol)
                 close = t0 + (max(durations) if durations
                               else eng.BASE_ROUND_S)
                 vc.push(close, events.BARRIER, payload=(r, late, on_time))
@@ -699,16 +771,16 @@ class FleetCore:
                         m.uplink_bytes += (self._entry_bytes
                                            + cfg.header_bytes)
                         m.n_messages += 1
-                    w0 = len(self._lf)
+                    w0 = self._learners.n
                     batch = late + on_time
                     self._merge_window([e for _, e in batch],
                                        [cid for cid, _ in batch],
                                        sync_round=r, compensated=False)
-                    delta = len(self._lf) - w0
+                    delta = self._learners.n - w0
                     pkg = delta * 16 + cfg.header_bytes
                     m.downlink_bytes += B * pkg
                     m.n_messages += B
-                    self._catch_up_fleet(w0, len(self._lf))
+                    self._catch_up_fleet(w0, self._learners.n)
                     m.n_syncs += 1
                     obs.count("train.syncs")
                     obs.count("train.learners_merged", delta)
@@ -719,7 +791,7 @@ class FleetCore:
         obs.count("train.events", vc.n_popped)
         m.sim_time_s = self._flush_late(pending_late, t)
 
-    def _flush_late(self, pending_late: List[Tuple[int, BufferEntry]],
+    def _flush_late(self, pending_late: List[Tuple[int, _FleetEntry]],
                     t: float) -> float:
         """Fleet mirror of the engine's ``_flush_late``: deliver + charge
         the final round's dropped messages, merge them stale-by-one at
@@ -797,7 +869,7 @@ class FleetCore:
                 obs.count("train.learners_merged", len(payload))
                 err = self._val_err()
                 eng.scheduler.observe(err)
-                delta = len(self._lf) - c.last_merged_idx
+                delta = self._learners.n - c.last_merged_idx
                 pkg = delta * 16 + cfg.header_bytes
                 m.downlink_bytes += pkg
                 m.n_messages += 1
@@ -811,7 +883,7 @@ class FleetCore:
         obs.count("train.events", vc.n_popped)
         m.sim_time_s = max(t, max(c.clock for c in self.clients))
 
-    def _prepare_sync(self, c) -> Tuple[float, List[BufferEntry]]:
+    def _prepare_sync(self, c) -> Tuple[float, List[_FleetEntry]]:
         """Fleet mirror of the engine's ``_prepare_sync``.  The relevance
         filter needs the buffered alphas, so an enabled filter forces the
         pending fits to resolve first (the filter is off in the shipped

@@ -165,9 +165,9 @@ def _job(monkeypatch, n_clients: int, mode: str, n_rounds: int = 2):
 def _assert_same_learning(a, b):
     core_a, m_a, _, waves_a, _ = a
     core_b, m_b, _, waves_b, _ = b
-    for col in ("_lf", "_lt", "_lp", "_la"):
-        assert np.array_equal(np.asarray(getattr(core_a, col)),
-                              np.asarray(getattr(core_b, col))), col
+    for col in ("f", "thr", "pol", "alpha", "owner"):
+        assert np.array_equal(core_a._learners[col],
+                              core_b._learners[col]), col
     assert len(waves_a) == len(waves_b)
     for (sa, ea, aa), (sb, eb, ab) in zip(waves_a, waves_b):
         assert np.array_equal(sa, sb)
