@@ -6,48 +6,37 @@ reference profile runs one device dispatch per client fit and one python
 merge per learner — fine at 32 clients, hopeless at 100 000.  The fleet
 profile keeps the *same event-queue semantics* but restructures the math:
 
-* **Stacked or packed shards, chosen from the fleet's shape.**  Client
-  shards are padded to the fleet's max rows and stacked into one
-  ``(B, N, F)`` array; padding rows carry zero distribution mass, which
-  every batched kernel treats as "contributes nothing".  The per-client
-  quantile threshold grids come from one ``stump_thresholds_batched``
-  launch at construction.  Where that stack would be mostly padding —
-  its *row fill*, real rows over ``B * N``, below ``PACKED_BELOW_FILL``
-  (1/4) — and the fleet fits one threshold chunk, the shards are packed
-  instead: the rows of every client back to back ``(R, F)``, with labels,
-  weights and each row's client ``(R,)`` and per-client offsets
-  ``(B+1,)``.  Their grids come from one ``stump_thresholds_segmented``
-  launch, in ``(B, T, F)``, and the fits from the ragged stump scan
-  (``fit_stump_ragged``), which keeps no per-client error grid.  The
-  crossover sits between the fleets the benchmark runs: LEAF FEMNIST
-  (2,048 writers, largest 573 rows) stacks at a fill of 39.6%, LEAF
-  Sent140 (16,384 users, mean 2.42 rows, largest 97) would stack at
-  2.49% — 47.7 GB at 7,500 features, where its packed rows are 1.19 GB —
-  and packs; an even split such as ``mobile_100k`` stacks at a fill near
-  100%.  Below a quarter, the stack launches four padding rows for every
-  real one, more than the ragged scan's per-row cost over the batched
-  scan's (a row at a time against a vectorized block) was taken to be;
-  where between 2.49% and 39.6% the two layouts really cross is left for
-  the two cells' numbers to decide.
-* **Resident wave staging.**  A fleet that fits one threshold chunk keeps
-  the chunk's rows and grids where the grid launch put them, on the device.
-  A fit wave of every client in order, padded to that chunk, reads them
-  there and stages only its labels and weights from the host; every other
-  wave (partial, permuted, a fleet of several chunks or of fewer than 8
-  clients) gathers and pads its four operands on the host.  The local
-  update gathers only the fitted feature column of each slot.  A packed
-  fleet keeps its rows and grids on the device the same way: a wave of
-  every client in order reads them there, and other waves gather their
-  clients' rows on the host and their grids on the device.  No host copy
-  of a packed fleet's grid is made.
+* **One shard layout, chosen from the fleet's shape.**  The shards are
+  padded to the fleet's most rows and stacked (:class:`_Padded`), unless
+  that stack's *row fill* (real rows over ``B * N``) is below
+  ``PACKED_BELOW_FILL`` and the fleet fits one ``THRESHOLD_CHUNK``: then
+  they are packed back to back (:class:`_Packed`).  Pad rows carry zero
+  weight, so no kernel or sum takes anything from them.  LEAF FEMNIST
+  (2,048 writers, largest 573 rows) stacks at a fill of 39.6%; LEAF
+  Sent140 (16,384 users, mean 2.42 rows) would stack at 2.49% — 47.7 GB
+  at 7,500 features against 1.19 GB packed — and packs.  The quarter
+  between them is a guess; the cells' numbers decide where the two
+  layouts really cross.
+* **The layout is the one seam.**  A layout owns what differs between
+  the two: staging the shards and their initial weights; the threshold
+  grid's launch, whose rows and grids a fleet of one chunk keeps on the
+  device, so that a fit wave of every client in order reads them there
+  and stages only its labels and weights (any other wave gathers on the
+  host); a wave's operands, weak fit and launch counters; and a *row
+  view* of some clients — labels, weights, feature columns, ``expand``
+  (a per-client value to its rows), ``segsum`` (rows summed per client)
+  and ``put`` (weights written back).  The fit wave, the local update,
+  both catch-ups and the stump vote (:func:`_vote`) are written once
+  above it, each layout running its own arithmetic op for op; the two
+  layouts sum in different orders and may differ in the last place.
 * **Deferred, batched fits.**  A client leg between syncs is causally
   closed, so its *timing* walk (availability/compute/stall/link draws — the
   behavior calls, in the reference call order) runs eagerly while the stump
   fits it implies are queued.  Pending fits resolve in dependency *waves* —
-  wave ``j`` fits round ``j`` of every pending leg in one bucketed
-  ``fit_stump_batched`` launch (batch padded to a power of two so the jit
-  cache stays small) — and each wave's local eps/alpha/distribution updates
-  run vectorized in numpy.
+  wave ``j`` fits round ``j`` of every pending leg in one bucketed launch
+  (batch padded to a power of two so the jit cache stays small) — and
+  each wave's local eps/alpha/distribution updates run vectorized in
+  numpy.
 * **Vectorized server math, tabulated per fit wave.**  What the server
   needs of a stump depends only on the stump and the held-out rows, so
   each fit wave computes it for all its stumps at once (in blocks of
@@ -73,16 +62,15 @@ Only the ``stump`` weak learner is supported — the batched launch path is
 stump-specific (the other learners never run at fleet scale).
 
 Tracing is per phase, never per client: a job is one ``train.fleet.run``
-span holding the shard stacking (``train.fleet.stack``, or
-``train.fleet.pack`` for packed shards), the threshold grids, the first
-timing
-walk, each fit wave (``train.fit_batch``: gather, transfer, fit) and its
-local update, one ``train.fleet.sync`` per arrival, and the finish; the
+span holding the layout's staging (``train.fleet.stack`` or
+``train.fleet.pack``), the threshold grids, the first timing walk, each
+fit wave (``train.fit_batch``: gather, transfer, fit) and its local
+update, one ``train.fleet.sync`` per arrival, and the finish; the
 ``train.fleet.*`` counters add the bytes handed to the device and copied
-on the host, the slots and rows the waves launch (for packed waves the
-rows, block padding included, and the clients), the waves that read
-the resident stack, and the stumps whose held-out votes were tabulated
-(outside any phase span, in ``train.fleet.run``'s self time).  With
+on the host, what the waves launch (slots and padded rows when stacked,
+rows and clients when packed), the waves that read the resident chunk,
+and the stumps whose held-out votes were tabulated (outside any phase
+span, in ``train.fleet.run``'s self time).  With
 ``obs.tracing(profiler=True)`` the spans land in a ``jax.profiler`` trace
 beside the device ops, so the device's idle time can be split by phase.
 """
@@ -121,6 +109,19 @@ def _next_pow2(n: int) -> int:
     while p < n:
         p *= 2
     return p
+
+
+def _vote(x, thr, pol):
+    """A stump's vote on feature values ``x``, elementwise: -1, 0 or +1."""
+    return pol * np.sign(x - thr + 1e-12)
+
+
+def _to_device(*arrays):
+    """Hand host arrays to the device, counting their bytes."""
+    import jax.numpy as jnp
+    with obs.span("train.fleet.h2d"):
+        obs.count("train.fleet.h2d_bytes", sum(a.nbytes for a in arrays))
+        return tuple(jnp.asarray(a) for a in arrays)
 
 
 @dataclass
@@ -171,8 +172,283 @@ def _newest_foreign(owners: np.ndarray, lo: int, cid: int,
     return idx if cap is None else idx[max(0, len(idx) - cap):]
 
 
+# ------------------------------------------------------------ shard layouts
+class _Padded:
+    """Shards padded to the fleet's most rows and stacked: ``X`` (B, N, F),
+    ``Y`` and ``D`` (B, N), client b in row b; a pad row has x = 0, y = 0
+    and D = 0.  The grids come back to the host as ``THR`` (B, F, T); a
+    fleet of one chunk also keeps the chunk's rows and grids on the
+    device (pad slots included) for the waves that read them in place."""
+
+    FIT, KERNEL = "fit_stump_batched", "stump_scan_batched"
+
+    def __init__(self, clients, n_valid: np.ndarray, balanced: bool) -> None:
+        with obs.span("train.fleet.stack"):
+            B, N = len(clients), int(n_valid.max())
+            F = int(np.asarray(clients[0].x).shape[1])
+            self.n_valid = n_valid
+            self.X = np.zeros((B, N, F), _F32)
+            self.Y = np.zeros((B, N), _F32)
+            self.D = np.zeros((B, N), _F32)
+            for b, c in enumerate(clients):
+                n = int(n_valid[b])
+                self.X[b, :n] = np.asarray(c.x, _F32)
+                self.Y[b, :n] = np.asarray(c.y, _F32)
+                yb = self.Y[b, :n]
+                if balanced:
+                    pos = (yb > 0).astype(_F32)
+                    npos = max(float(pos.sum()), 1.0)
+                    nneg = max(n - float(pos.sum()), 1.0)
+                    self.D[b, :n] = pos / (2 * npos) + (1 - pos) / (2 * nneg)
+                else:
+                    self.D[b, :n] = 1.0 / n
+        self._Xd = self._THRd = None
+
+    def build_grid(self) -> None:
+        """The grids, chunk by chunk."""
+        from repro.models.weak import stump_thresholds_batched
+        B = self.X.shape[0]
+        chunk = min(THRESHOLD_CHUNK, _next_pow2(B))
+        grids = []
+        for lo in range(0, B, chunk):
+            xb = self.X[lo:lo + chunk]
+            nb = self.n_valid[lo:lo + chunk]
+            pad = chunk - xb.shape[0]
+            if pad:
+                with obs.span("train.fleet.gather"):
+                    xb = np.concatenate([xb, np.zeros(
+                        (pad,) + xb.shape[1:], _F32)])
+                    nb = np.concatenate([nb, np.ones(pad, np.int32)])
+                    obs.count("train.fleet.gather_bytes",
+                              xb.nbytes + nb.nbytes)
+            args = _to_device(xb, nb)
+            with obs.span("train.fleet.grid_wait"):
+                g = stump_thresholds_batched(*args)
+                grids.append(np.asarray(g, _F32)[:xb.shape[0] - pad
+                                                 if pad else None])
+        if B <= chunk:
+            self._Xd, self._THRd = args[0], g
+        self.THR = np.concatenate(grids)[:B]                   # (B, F, T)
+
+    def release(self) -> None:
+        self._Xd = self._THRd = None
+
+    def wave(self, slots: np.ndarray) -> Tuple[int, bool]:
+        """A wave's slots, a power of two, and whether it is exactly the
+        resident chunk: every client, in order, with the chunk's pad."""
+        BP = max(8, _next_pow2(len(slots)))
+        return BP, (self._Xd is not None and self._Xd.shape[0] == BP
+                    and np.array_equal(slots, np.arange(len(self.X))))
+
+    def stage(self, slots: np.ndarray, BP: int, resident: bool):
+        """The batched fit's operands (X, Y, D, THR) on the device, padded
+        to ``BP`` zero-weight slots, and the wave's real rows."""
+        pad = BP - len(slots)
+        with obs.span("train.fleet.gather"):
+            ops = [self.Y[slots], self.D[slots]]
+            if not resident:
+                ops = [self.X[slots]] + ops + [self.THR[slots]]
+            staged = sum(a.nbytes for a in ops)
+            if pad:
+                ops = [np.concatenate(
+                    [a, np.zeros((pad,) + a.shape[1:], a.dtype)])
+                    for a in ops]
+                staged += sum(a.nbytes for a in ops)
+            obs.count("train.fleet.gather_bytes", staged)
+        args = _to_device(*ops)
+        if resident:
+            args = (self._Xd,) + args + (self._THRd,)
+        return args, int(self.n_valid[slots].sum())
+
+    def count_launch(self, Bw: int, BP: int, args) -> None:
+        obs.count("train.fleet.slots_launched", BP)
+        obs.count("train.fleet.rows_launched", BP * self.X.shape[1])
+
+    def view(self, slots=slice(None)) -> "_PaddedRows":
+        """The rows of ``slots``; every client's by default."""
+        return _PaddedRows(self, slots)
+
+    def rows_of(self, b: int) -> int:
+        return b
+
+
+class _Rows:
+    """A row view of some clients in a layout ``sh``, at ``at``: their
+    labels ``y`` and weights ``d`` (copies where ``at`` is an index
+    array); ``col(c)`` feature c of every row and ``fitted(f)`` each row's
+    value of its client's fitted feature; ``expand`` takes a per-client
+    value to the rows, ``segsum`` sums the rows per client, and ``put``
+    writes the weights back."""
+
+    def __init__(self, sh, at) -> None:
+        self.sh, self.at = sh, at
+        self.y, self.d = sh.Y[at], sh.D[at]
+
+    def put(self, d: np.ndarray) -> None:
+        self.sh.D[self.at] = d
+
+
+class _PaddedRows(_Rows):
+    """Padded clients: a client's values broadcast along its row, and its
+    row sums along that axis."""
+
+    def col(self, c):
+        """Feature ``c`` of every row, or with one ``c`` per client, each
+        client's own."""
+        return self.sh.X[self.at, :, c]
+
+    fitted = col
+
+    def expand(self, v: np.ndarray) -> np.ndarray:
+        return v[:, None]
+
+    def segsum(self, a: np.ndarray) -> np.ndarray:
+        return np.sum(a, axis=1, dtype=_F32)
+
+
+class _Packed:
+    """Shards packed back to back: ``X`` (Rp, F), ``Y``, ``D`` and each
+    row's client ``seg`` (Rp,), client b's rows at ``off[b]:off[b+1]``; the
+    ``R`` real rows are padded to a multiple of ``ROW_ALIGN`` with rows of
+    no client (seg = B, x = 0, y = 0, D = 0).  The grids (B, T, F) come
+    from one launch and stay on the device with the rows and row clients;
+    nothing of them comes back to the host."""
+
+    FIT, KERNEL = "fit_stump_ragged", "stump_scan_ragged"
+
+    def __init__(self, clients, n_valid: np.ndarray, balanced: bool) -> None:
+        with obs.span("train.fleet.pack"):
+            B = len(clients)
+            n = n_valid.astype(np.int64)
+            self.n_valid = n_valid
+            self.off = np.concatenate([[0], np.cumsum(n)])
+            self.R = R = int(self.off[-1])
+            Rp = -(-R // ROW_ALIGN) * ROW_ALIGN
+            F = int(np.asarray(clients[0].x).shape[1])
+            self.X = np.zeros((Rp, F), _F32)
+            np.concatenate([np.asarray(c.x, _F32) for c in clients],
+                           out=self.X[:R])
+            self.Y = np.zeros(Rp, _F32)
+            np.concatenate([np.asarray(c.y, _F32) for c in clients],
+                           out=self.Y[:R])
+            self.seg = np.full(Rp, B, np.int32)
+            self.seg[:R] = np.repeat(np.arange(B, dtype=np.int32), n)
+            self.D = np.zeros(Rp, _F32)
+            s = self.seg[:R]
+            if balanced:
+                pos = (self.Y[:R] > 0).astype(_F32)
+                npos = np.add.reduceat(pos, self.off[:-1]).astype(np.float64)
+                nneg = np.maximum(n - npos, 1.0).astype(_F32)
+                npos = np.maximum(npos, 1.0).astype(_F32)
+                self.D[:R] = pos / (2 * npos[s]) + (1 - pos) / (2 * nneg[s])
+            else:
+                self.D[:R] = (1.0 / n)[s]
+        self._Xd = self._THRd = self._segd = None
+
+    def build_grid(self) -> None:
+        from repro.models.weak import stump_thresholds_segmented
+        args = _to_device(self.X, self.seg, self.n_valid)
+        with obs.span("train.fleet.grid_wait"):
+            g = stump_thresholds_segmented(*args)
+            g.block_until_ready()
+        self._Xd, self._segd, self._THRd = args[0], args[1], g
+
+    def release(self) -> None:
+        self._Xd = self._THRd = self._segd = None
+
+    def wave(self, slots: np.ndarray) -> Tuple[int, bool]:
+        """A wave's clients and whether it reads the resident rows: every
+        client in order does, at its own count; any other wave pads its
+        clients to a power of two."""
+        resident = np.array_equal(slots, np.arange(len(self.n_valid)))
+        return (len(slots) if resident else max(8, _next_pow2(len(slots))),
+                resident)
+
+    def stage(self, slots: np.ndarray, BP: int, resident: bool):
+        """The ragged fit's operands (X, Y, D, seg, grids) on the device,
+        and the wave's real rows.  A wave that is not resident gathers its
+        clients' rows on the host, padded to a power of two (at least
+        ``ROW_ALIGN``) with rows of no client, and their grids on the
+        device, padded to ``BP`` clients."""
+        with obs.span("train.fleet.gather"):
+            if resident:
+                ops, real = [self.Y, self.D], self.R
+            else:
+                v = self.view(slots)
+                real = len(v.at)
+                Rp = max(ROW_ALIGN, _next_pow2(real))
+                ops = [np.zeros((Rp,) + a.shape[1:], a.dtype)
+                       for a in (self.X, self.Y, self.D)]
+                for o, a in zip(ops, (self.X[v.at], v.y, v.d)):
+                    o[:real] = a
+                seg = np.full(Rp, BP, np.int32)
+                seg[:real] = v.local
+                ops.append(seg)
+                obs.count("train.fleet.gather_bytes",
+                          sum(a.nbytes for a in ops))
+        args = _to_device(*ops)
+        if resident:
+            return (self._Xd,) + args + (self._segd, self._THRd), real
+        import jax.numpy as jnp
+        idx = np.zeros(BP, np.int32)
+        idx[:len(slots)] = slots
+        thr = jnp.take(self._THRd, jnp.asarray(idx), axis=0)
+        return args + (thr,), real
+
+    def count_launch(self, Bw: int, BP: int, args) -> None:
+        obs.count("train.fleet.packed_rows_launched", args[0].shape[0])
+        obs.count("train.fleet.segments_launched", Bw)
+
+    def view(self, slots: Optional[np.ndarray] = None) -> "_PackedRows":
+        """The rows of ``slots``, client by client; every client's by
+        default."""
+        if slots is None:
+            return _PackedRows(self, slice(0, self.R), self.seg[:self.R],
+                               self.off[:-1])
+        lens = self.n_valid[slots].astype(np.int64)
+        starts = np.concatenate([[0], np.cumsum(lens)[:-1]])
+        local = np.repeat(np.arange(len(slots)), lens)
+        rows = self.off[slots][local] + (np.arange(int(lens.sum()))
+                                         - starts[local])
+        return _PackedRows(self, rows, local, starts)
+
+    def rows_of(self, b: int) -> slice:
+        return slice(self.off[b], self.off[b + 1])
+
+
+class _PackedRows(_Rows):
+    """Packed clients: ``local`` is each row's client in the view and
+    ``starts`` where each client's rows begin, so a client's values reach
+    its rows by ``local`` and its rows sum by segment."""
+
+    def __init__(self, sh, at, local, starts) -> None:
+        super().__init__(sh, at)
+        self.local, self.starts = local, starts
+
+    def col(self, c):
+        """Feature ``c`` of every row."""
+        return self.sh.X[self.at, c]
+
+    def fitted(self, f: np.ndarray) -> np.ndarray:
+        """Each row's value of the feature its client fitted."""
+        return self.col(f[self.local])
+
+    def expand(self, v: np.ndarray) -> np.ndarray:
+        return v[self.local]
+
+    def segsum(self, a: np.ndarray) -> np.ndarray:
+        return np.add.reduceat(a, self.starts, dtype=_F32)
+
+
 class FleetCore:
     """One engine run in the vectorized fleet profile."""
+
+    # the distributions (assignable, replacing the layout's) and packed
+    # offsets: client b's weights are D[b] stacked, D[off[b]:off[b + 1]]
+    # packed
+    D = property(lambda self: self.shards.D,
+                 lambda self, d: setattr(self.shards, "D", d))
+    off = property(lambda self: self.shards.off)
 
     def __init__(self, eng) -> None:
         if eng.weak.name != "stump":
@@ -190,16 +466,11 @@ class FleetCore:
         N = int(self.n_valid.max())
         self.packed = (B <= THRESHOLD_CHUNK and int(self.n_valid.sum())
                        < PACKED_BELOW_FILL * B * N)
-        self._Xd = self._THRd = self._segd = None
-        if self.packed:
-            with obs.span("train.fleet.pack"):
-                self._pack()
-            with obs.span("train.fleet.thresholds"):
-                self._build_thresholds_packed()
-        else:
-            self._stack(N)
-            with obs.span("train.fleet.thresholds"):
-                self.THR = self._build_thresholds()            # (B, F, T)
+        layout = _Packed if self.packed else _Padded
+        self.shards = layout(self.clients, self.n_valid,
+                             self.cfg.balanced_init)
+        with obs.span("train.fleet.thresholds"):
+            self.shards.build_grid()
 
         # ---- server state mirrors (numpy-side ensemble view) ----
         xv, yv = eng.data["val"]
@@ -224,107 +495,7 @@ class FleetCore:
         self._entry_bytes = (int(eng.weak.param_bytes(None))
                              + ENTRY_OVERHEAD_BYTES)
 
-    def _stack(self, N: int) -> None:
-        """Stacked, padded shards (pad rows: x=0, y=0, D=0)."""
-        B = len(self.clients)
-        with obs.span("train.fleet.stack"):
-            F = int(np.asarray(self.clients[0].x).shape[1])
-            self.X = np.zeros((B, N, F), _F32)
-            self.Y = np.zeros((B, N), _F32)
-            self.D = np.zeros((B, N), _F32)
-            for b, c in enumerate(self.clients):
-                n = int(self.n_valid[b])
-                self.X[b, :n] = np.asarray(c.x, _F32)
-                self.Y[b, :n] = np.asarray(c.y, _F32)
-                yb = self.Y[b, :n]
-                if self.cfg.balanced_init:
-                    pos = (yb > 0).astype(_F32)
-                    npos = max(float(pos.sum()), 1.0)
-                    nneg = max(n - float(pos.sum()), 1.0)
-                    self.D[b, :n] = pos / (2 * npos) + (1 - pos) / (2 * nneg)
-                else:
-                    self.D[b, :n] = 1.0 / n
-
-    def _pack(self) -> None:
-        """Packed shards: every client's rows back to back, padded to
-        ``ROW_ALIGN`` rows with rows of no client (seg = B, x=0, y=0,
-        D=0); ``off[b]:off[b+1]`` are client b's rows."""
-        B = len(self.clients)
-        n = self.n_valid.astype(np.int64)
-        self.off = np.concatenate([[0], np.cumsum(n)])
-        R = int(self.off[-1])
-        Rp = -(-R // ROW_ALIGN) * ROW_ALIGN
-        F = int(np.asarray(self.clients[0].x).shape[1])
-        self.X = np.zeros((Rp, F), _F32)
-        np.concatenate([np.asarray(c.x, _F32) for c in self.clients],
-                       out=self.X[:R])
-        self.Y = np.zeros(Rp, _F32)
-        np.concatenate([np.asarray(c.y, _F32) for c in self.clients],
-                       out=self.Y[:R])
-        self.seg = np.full(Rp, B, np.int32)
-        self.seg[:R] = np.repeat(np.arange(B, dtype=np.int32), n)
-        self.D = np.zeros(Rp, _F32)
-        s = self.seg[:R]
-        if self.cfg.balanced_init:
-            pos = (self.Y[:R] > 0).astype(_F32)
-            npos = np.add.reduceat(pos, self.off[:-1]).astype(np.float64)
-            nneg = np.maximum(n - npos, 1.0).astype(_F32)
-            npos = np.maximum(npos, 1.0).astype(_F32)
-            self.D[:R] = pos / (2 * npos[s]) + (1 - pos) / (2 * nneg[s])
-        else:
-            self.D[:R] = (1.0 / n)[s]
-
     # ------------------------------------------------------------ batched fits
-    @staticmethod
-    def _to_device(*arrays):
-        """Hand host arrays to the device, counting their bytes."""
-        import jax.numpy as jnp
-        with obs.span("train.fleet.h2d"):
-            obs.count("train.fleet.h2d_bytes",
-                      sum(a.nbytes for a in arrays))
-            return tuple(jnp.asarray(a) for a in arrays)
-
-    def _build_thresholds(self) -> np.ndarray:
-        """The host grids, chunk by chunk.  A fleet of one chunk keeps
-        that chunk's device rows and grids as ``_Xd`` / ``_THRd`` (pad
-        slots included) for the fit waves that read them in place."""
-        from repro.models.weak import stump_thresholds_batched
-        B = self.X.shape[0]
-        chunk = min(THRESHOLD_CHUNK, _next_pow2(B))
-        self._Xd = self._THRd = None
-        grids = []
-        for lo in range(0, B, chunk):
-            xb = self.X[lo:lo + chunk]
-            nb = self.n_valid[lo:lo + chunk]
-            pad = chunk - xb.shape[0]
-            if pad:
-                with obs.span("train.fleet.gather"):
-                    xb = np.concatenate([xb, np.zeros(
-                        (pad,) + xb.shape[1:], _F32)])
-                    nb = np.concatenate([nb, np.ones(pad, np.int32)])
-                    obs.count("train.fleet.gather_bytes",
-                              xb.nbytes + nb.nbytes)
-            args = self._to_device(xb, nb)
-            with obs.span("train.fleet.grid_wait"):
-                g = stump_thresholds_batched(*args)
-                grids.append(np.asarray(g, _F32)[:xb.shape[0] - pad
-                                                 if pad else None])
-        if B <= chunk:
-            self._Xd, self._THRd = args[0], g
-        return np.concatenate(grids)[:B]
-
-    def _build_thresholds_packed(self) -> None:
-        """The packed fleet's grids, in one launch, kept on the device
-        with its rows and row clients (``_Xd``, ``_THRd``, ``_segd``);
-        nothing of the grid comes back to the host."""
-        from repro.models.weak import stump_thresholds_segmented
-        self.THR = None
-        args = self._to_device(self.X, self.seg, self.n_valid)
-        with obs.span("train.fleet.grid_wait"):
-            g = stump_thresholds_segmented(*args)
-            g.block_until_ready()
-        self._Xd, self._segd, self._THRd = args[0], args[1], g
-
     def _fit_backend(self, xb, kernel: str = "stump_scan_batched"
                      ) -> Optional[str]:
         """Resolve the batched-fit backend.  No policy keeps the jnp
@@ -344,118 +515,29 @@ class FleetCore:
             policy.choices[(kernel, bucket)] = name
         return name
 
-    def _reads_resident(self, slots: np.ndarray, BP: int) -> bool:
-        """Whether a wave over ``slots`` padded to ``BP`` is exactly the
-        resident chunk: every client, in order, with the chunk's pad."""
-        return (self._Xd is not None and self._Xd.shape[0] == BP
-                and np.array_equal(slots, np.arange(len(self.clients))))
-
     def _fit_wave(self, slots: np.ndarray
                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """One bucketed batched-fit launch over ``slots`` (client rows),
-        padded to a power of two with zero-weight slots.  A wave that is
-        the resident chunk stages only its labels and weights."""
-        if self.packed:
-            return self._fit_wave_packed(slots)
-        from repro.models.weak import fit_stump_batched
-        Bw = len(slots)
-        BP = max(8, _next_pow2(Bw))
-        pad = BP - Bw
-        resident = self._reads_resident(slots, BP)
+        """One fit launch over ``slots`` (clients), padded to a power of
+        two with zero-weight slots or rows, staged by the layout.  A wave
+        that is the resident chunk stages only its labels and weights."""
+        from repro.models import weak
+        sh, Bw = self.shards, len(slots)
+        BP, resident = sh.wave(slots)
         with obs.span("train.fit_batch", n_slots=Bw, padded=BP):
-            with obs.span("train.fleet.gather"):
-                ops = [self.Y[slots], self.D[slots]]
-                if not resident:
-                    ops = [self.X[slots]] + ops + [self.THR[slots]]
-                staged = sum(a.nbytes for a in ops)
-                if pad:
-                    ops = [np.concatenate(
-                        [a, np.zeros((pad,) + a.shape[1:], a.dtype)])
-                        for a in ops]
-                    staged += sum(a.nbytes for a in ops)
-                obs.count("train.fleet.gather_bytes", staged)
-            args = self._to_device(*ops)
+            args, real = sh.stage(slots, BP, resident)
             if resident:
-                args = (self._Xd,) + args + (self._THRd,)
                 obs.count("train.fleet.resident_waves")
             with obs.span("train.fleet.fit"):
-                params = fit_stump_batched(*args,
-                                           backend=self._fit_backend(args))
+                # looked up at each call, so a wrapper set on the module
+                # sees every wave
+                params = getattr(weak, sh.FIT)(
+                    *args, backend=self._fit_backend(args, sh.KERNEL))
                 f = np.asarray(params["feature"])[:Bw].astype(np.int64)
                 thr = np.asarray(params["threshold"], _F32)[:Bw]
                 pol = np.asarray(params["polarity"], _F32)[:Bw]
         obs.count("train.fit_batches")
         obs.count("train.fits", Bw)
-        obs.count("train.fleet.slots_launched", BP)
-        obs.count("train.fleet.rows_launched", BP * self.X.shape[1])
-        obs.count("train.fleet.real_rows", int(self.n_valid[slots].sum()))
-        return f, thr, pol
-
-    def _wave_rows(self, slots: np.ndarray):
-        """The packed rows of ``slots``, client by client, each row's
-        index in the wave's client list, and where each client starts in
-        that order."""
-        lens = self.n_valid[slots].astype(np.int64)
-        starts = np.concatenate([[0], np.cumsum(lens)[:-1]])
-        local = np.repeat(np.arange(len(slots)), lens)
-        rows = self.off[slots][local] + (np.arange(int(lens.sum()))
-                                         - starts[local])
-        return rows, local, starts
-
-    def _fit_wave_packed(self, slots: np.ndarray
-                         ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """One ragged-fit launch over the packed rows of ``slots``.  A
-        wave of every client in order reads the rows, row clients and
-        grids where the grid launch left them and stages its labels and
-        weights; any other wave gathers its clients' rows on the host
-        (padded to a power of two, at least ``ROW_ALIGN``, with rows of
-        no client) and their grids on the device (clients padded to a
-        power of two)."""
-        import jax.numpy as jnp
-        from repro.models.weak import fit_stump_ragged
-        B, Bw = len(self.clients), len(slots)
-        resident = np.array_equal(slots, np.arange(B))
-        BP = Bw if resident else max(8, _next_pow2(Bw))
-        with obs.span("train.fit_batch", n_slots=Bw, padded=BP):
-            with obs.span("train.fleet.gather"):
-                if resident:
-                    ops = [self.Y, self.D]
-                    real = int(self.off[-1])
-                else:
-                    rows, local, _ = self._wave_rows(slots)
-                    real = len(rows)
-                    Rp = max(ROW_ALIGN, _next_pow2(real))
-                    ops = [np.zeros((Rp,) + a.shape[1:], a.dtype)
-                           for a in (self.X, self.Y, self.D)]
-                    for o, a in zip(ops, (self.X, self.Y, self.D)):
-                        o[:real] = a[rows]
-                    seg = np.full(Rp, BP, np.int32)
-                    seg[:real] = local
-                    ops.append(seg)
-                    obs.count("train.fleet.gather_bytes",
-                              sum(a.nbytes for a in ops))
-            args = self._to_device(*ops)
-            if resident:
-                x, seg, thr = self._Xd, self._segd, self._THRd
-                y, w = args
-                obs.count("train.fleet.resident_waves")
-            else:
-                x, y, w, seg = args
-                idx = np.zeros(BP, np.int32)
-                idx[:Bw] = slots
-                thr = jnp.take(self._THRd, jnp.asarray(idx), axis=0)
-            with obs.span("train.fleet.fit"):
-                params = fit_stump_ragged(
-                    x, y, w, seg, thr,
-                    backend=self._fit_backend((x, y, w, seg, thr),
-                                              "stump_scan_ragged"))
-                f = np.asarray(params["feature"])[:Bw].astype(np.int64)
-                thr = np.asarray(params["threshold"], _F32)[:Bw]
-                pol = np.asarray(params["polarity"], _F32)[:Bw]
-        obs.count("train.fit_batches")
-        obs.count("train.fits", Bw)
-        obs.count("train.fleet.packed_rows_launched", x.shape[0])
-        obs.count("train.fleet.segments_launched", Bw)
+        sh.count_launch(Bw, BP, args)
         obs.count("train.fleet.real_rows", real)
         return f, thr, pol
 
@@ -465,45 +547,20 @@ class FleetCore:
         """Vectorized mirror of the reference ``_train_one`` tail: eps on
         the pre-update distribution, the local alpha, and the eq.-(4)
         distribution update, for every fitted slot at once."""
-        if self.packed:
-            return self._local_update_packed(slots, f, thr, pol)
         with obs.span("train.fleet.local_update"):
             with obs.span("train.fleet.gather"):
-                # the fitted column of each slot: (Bw, N)
-                xsel = self.X[slots, :, f]
-                yb, Db = self.Y[slots], self.D[slots]
+                v = self.shards.view(slots)
+                xsel, yb, Db = v.fitted(f), v.y, v.d
                 obs.count("train.fleet.gather_bytes",
                           xsel.nbytes + yb.nbytes + Db.nbytes)
-            h = pol[:, None] * np.sign(xsel - thr[:, None] + 1e-12)
+            h = _vote(xsel, v.expand(thr), v.expand(pol))
             pred = np.where(h > 0, 1.0, -1.0).astype(_F32)
-            eps = np.sum(Db * (pred != yb), axis=1, dtype=_F32)
+            eps = v.segsum(Db * (pred != yb))
             epsc = np.clip(eps, 1e-6, 1.0 - 1e-6)
             alpha = (0.5 * np.log((1.0 - epsc) / epsc)).astype(_F32)
-            w = Db * np.exp(-alpha[:, None] * yb * h)
-            Z = np.sum(w, axis=1, dtype=_F32)
-            self.D[slots] = w / (Z[:, None] + 1e-30)
-        return eps, alpha
-
-    def _local_update_packed(self, slots: np.ndarray, f: np.ndarray,
-                             thr: np.ndarray, pol: np.ndarray
-                             ) -> Tuple[np.ndarray, np.ndarray]:
-        """:meth:`_local_update` over packed rows: each row reads the
-        feature its client fitted, and the sums run per client segment."""
-        with obs.span("train.fleet.local_update"):
-            with obs.span("train.fleet.gather"):
-                rows, local, starts = self._wave_rows(slots)
-                xsel = self.X[rows, f[local]]
-                yb, Db = self.Y[rows], self.D[rows]
-                obs.count("train.fleet.gather_bytes",
-                          xsel.nbytes + yb.nbytes + Db.nbytes)
-            h = pol[local] * np.sign(xsel - thr[local] + 1e-12)
-            pred = np.where(h > 0, 1.0, -1.0).astype(_F32)
-            eps = np.add.reduceat(Db * (pred != yb), starts, dtype=_F32)
-            epsc = np.clip(eps, 1e-6, 1.0 - 1e-6)
-            alpha = (0.5 * np.log((1.0 - epsc) / epsc)).astype(_F32)
-            w = Db * np.exp(-alpha[local] * yb * h)
-            Z = np.add.reduceat(w, starts, dtype=_F32)
-            self.D[rows] = w / (Z[local] + 1e-30)
+            w = Db * np.exp(-v.expand(alpha) * yb * h)
+            Z = v.segsum(w)
+            v.put(w / (v.expand(Z) + 1e-30))
         return eps, alpha
 
     def _defer_fit(self, c) -> _FleetEntry:
@@ -552,8 +609,8 @@ class FleetCore:
             s = slice(lo, min(lo + SERVER_CHUNK, Bw))
             t, p = thr[s, None], pol[s, None]
             # (stumps, rows): the predictor's arithmetic, element by element
-            hv = p * np.sign(self._xv_f[f[s]] - t + 1e-12)
-            ht = p * np.sign(self._xt_f[f[s]] - t + 1e-12)
+            hv = _vote(self._xv_f[f[s]], t, p)
+            ht = _vote(self._xt_f[f[s]], t, p)
             codes = np.zeros((len(hv), max(nv, nt)), np.uint8)
             codes[:, :nv] = hv + 1
             codes[:, :nt] += (4 * ht + 4).astype(np.uint8)
@@ -648,32 +705,16 @@ class FleetCore:
             rev = foreign[:, ::-1]
             sel = (rev & (np.cumsum(rev, axis=1) <= cap))[:, ::-1]
         cf, ct, cp, ca = (L[k][cand] for k in ("f", "thr", "pol", "alpha"))
-        if self.packed:
-            self._catch_up_packed(sel, cf, ct, cp, ca)
-        else:
-            Macc = np.zeros_like(self.D)
-            for w in range(W):
-                h = cp[w] * np.sign(self.X[:, :, cf[w]] - ct[w] + 1e-12)
-                Macc += (ca[w] * sel[:, w].astype(_F32))[:, None] * h
-            wgt = self.D * np.exp(-self.Y * Macc)
-            Z = np.sum(wgt, axis=1, dtype=_F32)
-            self.D = wgt / (Z[:, None] + 1e-30)
+        v = self.shards.view()
+        Macc = np.zeros_like(v.d)
+        for w in range(W):
+            h = _vote(v.col(cf[w]), ct[w], cp[w])
+            Macc += v.expand(ca[w] * sel[:, w].astype(_F32)) * h
+        wgt = v.d * np.exp(-v.y * Macc)
+        Z = v.segsum(wgt)
+        v.put(wgt / (v.expand(Z) + 1e-30))
         for c in self.clients:
             c.last_merged_idx = w1
-
-    def _catch_up_packed(self, sel, cf, ct, cp, ca) -> None:
-        """The fleet-wide fold of :meth:`_catch_up_fleet` over packed
-        rows: each row takes its client's mask, the sums run per client
-        segment."""
-        R = int(self.off[-1])
-        s = self.seg[:R]
-        Macc = np.zeros(R, _F32)
-        for w in range(len(cf)):
-            h = cp[w] * np.sign(self.X[:R, cf[w]] - ct[w] + 1e-12)
-            Macc += (ca[w] * sel[:, w].astype(_F32))[s] * h
-        wgt = self.D[:R] * np.exp(-self.Y[:R] * Macc)
-        Z = np.add.reduceat(wgt, self.off[:-1], dtype=_F32)
-        self.D[:R] = wgt / (Z[s] + 1e-30)
 
     def _catch_up_client(self, c) -> None:
         """Per-client capped catch-up at its own sync (enhanced mode):
@@ -686,15 +727,13 @@ class FleetCore:
         c.last_merged_idx = L.n
         if not len(idxs):
             return
-        b = c.cid
+        sh = self.shards
+        rows = sh.rows_of(c.cid)
         f, thr, pol, a = (L[k][idxs] for k in ("f", "thr", "pol", "alpha"))
-        rows = (slice(self.off[b], self.off[b + 1]) if self.packed
-                else b)
-        h = pol[None, :] * np.sign(self.X[rows][:, f] - thr[None, :]
-                                   + 1e-12)
-        wgt = self.D[rows] * np.exp(-self.Y[rows] * (h @ a))
+        h = _vote(sh.X[rows][:, f], thr[None, :], pol[None, :])
+        wgt = sh.D[rows] * np.exp(-sh.Y[rows] * (h @ a))
         Z = float(np.sum(wgt, dtype=_F32))
-        self.D[rows] = wgt / (Z + 1e-30)
+        sh.D[rows] = wgt / (Z + 1e-30)
 
     # ---------------------------------------------------------------- run
     def run(self) -> None:
@@ -710,10 +749,10 @@ class FleetCore:
                 # hand the accumulated margins back so the engine's
                 # _finalize / _val_error see the fleet-computed state
                 self.eng._val_margin, self.eng._test_margin = (
-                    self._to_device(self.Mval, self.Mtest))
+                    _to_device(self.Mval, self.Mtest))
                 self.eng._finalize()
         finally:
-            self._Xd = self._THRd = self._segd = None
+            self.shards.release()
 
     def _run_baseline(self) -> None:
         """Synchronous baseline, fleet profile.  Same TRIGGER/BARRIER

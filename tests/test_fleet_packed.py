@@ -175,8 +175,9 @@ def test_the_layout_follows_the_fleet_shape(counts, packed):
                                                 fleet=True))
     assert core.packed == packed
     if packed:
-        assert core.X.shape[0] % fleet.ROW_ALIGN == 0
-        assert core.off[-1] == counts.sum() and core.THR is None
-        assert core._THRd.shape == (len(counts), 16, 128)
+        assert core.shards.X.shape[0] % fleet.ROW_ALIGN == 0
+        assert (core.off[-1] == counts.sum()
+                and getattr(core.shards, "THR", None) is None)
+        assert core.shards._THRd.shape == (len(counts), 16, 128)
     else:
-        assert core.X.shape == (len(counts), counts.max(), 8)
+        assert core.shards.X.shape == (len(counts), counts.max(), 8)

@@ -104,9 +104,9 @@ class _PerArrival(fleet.FleetCore):
         a = np.array([self._la[i] for i in idxs], _F32)
         rows = (slice(self.off[b], self.off[b + 1]) if self.packed
                 else b)
-        h = pol[None, :] * np.sign(self.X[rows][:, f] - thr[None, :]
-                                   + 1e-12)
-        wgt = self.D[rows] * np.exp(-self.Y[rows] * (h @ a))
+        X, Y = self.shards.X, self.shards.Y
+        h = pol[None, :] * np.sign(X[rows][:, f] - thr[None, :] + 1e-12)
+        wgt = self.D[rows] * np.exp(-Y[rows] * (h @ a))
         Z = float(np.sum(wgt, dtype=_F32))
         self.D[rows] = wgt / (Z + 1e-30)
 

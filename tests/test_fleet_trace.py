@@ -151,7 +151,7 @@ def _job(monkeypatch, n_clients: int, mode: str, n_rounds: int = 2):
     old = obs.set_registry(obs.MetricsRegistry())
     try:
         core = fleet.FleetCore(eng)
-        refs = [weakref.ref(a) for a in (core._Xd, core._THRd)
+        refs = [weakref.ref(a) for a in (core.shards._Xd, core.shards._THRd)
                 if a is not None]
         core.run()
         counters = {name: c.value for name, _, c in
@@ -198,7 +198,7 @@ def test_resident_waves_match_host_staged_waves_bit_for_bit(monkeypatch,
     for ref in resident[4]:               # released by the end of run()
         assert ref() is None
     for core, *_ in (resident, host):
-        assert core._Xd is None and core._THRd is None
+        assert core.shards._Xd is None and core.shards._THRd is None
 
 
 def test_partial_waves_take_the_host_path(monkeypatch):
@@ -222,10 +222,51 @@ def test_partial_waves_take_the_host_path(monkeypatch):
                          ids=["partial", "reversed", "swapped"])
 def test_only_the_whole_fleet_in_order_reads_the_resident_stack(slots):
     core = fleet.FleetCore(_engine(64)[0])
-    assert core._Xd.shape[0] == 64 and core._THRd.shape[0] == 64
-    assert core._reads_resident(np.arange(64), 64)
-    assert not core._reads_resident(slots, max(8, fleet._next_pow2(
-        len(slots))))
+    assert core.shards._Xd.shape[0] == 64 and core.shards._THRd.shape[0] == 64
+    assert core.shards.wave(np.arange(64)) == (64, True)
+    assert core.shards.wave(slots) == (max(8, fleet._next_pow2(
+        len(slots))), False)
+
+
+@pytest.mark.parametrize("mode", ["baseline", "enhanced"])
+@pytest.mark.parametrize("layout", ["padded", "packed"])
+def test_the_benchmark_taps_see_every_wave(monkeypatch, layout, mode):
+    """The benchmark's drivers follow a job by wrapping
+    ``FleetCore._local_update`` on the class and the layout's weak fit on
+    ``repro.models.weak``, as below: each must be called through those
+    names exactly once per fit wave, resident or gathered on the host."""
+    import repro.models.weak as weak
+    packed = layout == "packed"
+    monkeypatch.setattr(fleet, "PACKED_BELOW_FILL", 2.0 if packed else 0.0)
+    name = "fit_stump_ragged" if packed else "fit_stump_batched"
+    fit, update = getattr(weak, name), fleet.FleetCore._local_update
+    calls, layouts = {"fit": 0, "update": 0}, set()
+
+    def fit_tap(*args, **kw):
+        calls["fit"] += 1
+        return fit(*args, **kw)
+
+    def update_tap(core, slots, f, thr, pol):
+        calls["update"] += 1
+        layouts.add(core.packed)
+        return update(core, slots, f, thr, pol)
+
+    monkeypatch.setattr(weak, name, fit_tap)
+    monkeypatch.setattr(fleet.FleetCore, "_local_update", update_tap)
+    eng, _ = _engine(64, mode, n_rounds=4)
+    old = obs.set_registry(obs.MetricsRegistry())
+    try:
+        eng.run()
+        reg = obs.get_registry()
+        waves = reg.counter("train.fit_batches").value
+        resident = reg.counter("train.fleet.resident_waves").value
+    finally:
+        obs.set_registry(old)
+    assert layouts == {packed}
+    assert resident > 0
+    if mode == "enhanced":            # waves after a sync are partial
+        assert resident < waves
+    assert calls == {"fit": waves, "update": waves}
 
 
 def test_a_fleet_below_eight_clients_keeps_the_host_path(monkeypatch):
